@@ -161,7 +161,15 @@ def _worker(full: bool) -> list:
 def run(full: bool = FULL):
     """Spawn the worker with the forced host-device mesh (jax locks the
     device count at first init, and this parent process has already
-    initialized jax via the other figure modules)."""
+    initialized jax via the other figure modules). The worker times the
+    CPU's virtual devices, so on a host with a TPU the figure refuses
+    instead of reporting CPU times there."""
+    import jax
+
+    if jax.default_backend() == "tpu":
+        raise RuntimeError(
+            "fig_hier times an 8-device CPU host mesh in a child process; "
+            "on a TPU host that would report CPU times, so it refuses")
     env = dict(os.environ)
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={_DEVICES}"
     env.setdefault("JAX_PLATFORMS", "cpu")

@@ -15,7 +15,6 @@ import os
 from typing import Any, Dict
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 
 
@@ -82,7 +81,9 @@ def load_checkpoint(path: str, like) -> Any:
     Raises ``KeyError`` when the checkpoint's key set and the template's
     disagree (listing the missing / unexpected paths) and ``ValueError``
     on a per-leaf shape mismatch — both conditions previously restored
-    garbage silently when flatten order happened to differ.
+    garbage silently when flatten order happened to differ. Leaves come
+    back as host (numpy) arrays in the template's dtypes; the caller
+    decides where they go.
     """
     pairs, treedef = jax.tree_util.tree_flatten_with_path(like)
     keyed = {}
@@ -110,6 +111,5 @@ def load_checkpoint(path: str, like) -> Any:
                     f"checkpoint leaf {key!r} has shape {arr.shape}, "
                     f"template expects {tuple(np.shape(leaf))}")
             dt = leaf.dtype if hasattr(leaf, "dtype") else None
-            restored.append(jnp.asarray(arr).astype(dt) if dt is not None
-                            else jnp.asarray(arr))
+            restored.append(arr.astype(dt) if dt is not None else arr)
     return jax.tree_util.tree_unflatten(treedef, restored)

@@ -93,6 +93,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .gossip import mix_dot
+
 # compression RNG domain: the stochastic-rounding noise of round t is drawn
 # from fold_in(round_key, COMPRESS_KEY_FOLD). The constant is far outside
 # the engine's per-client fold domain (0..K-1) so codec noise can never
@@ -230,8 +232,8 @@ def compressed_pushsum_mix(flat, w, P, pub, key, spec: CompressionSpec):
     Pf = jnp.asarray(P, jnp.float32)
     kept, sent = _split_P(Pf)
     c, pub2 = _ef_encode(f, pub, sent, key, spec)
-    mixed = kept[:, None] * f + sent @ pub2
-    w2 = Pf @ w.astype(jnp.float32)
+    mixed = kept[:, None] * f + mix_dot(sent, pub2)
+    w2 = mix_dot(Pf, w.astype(jnp.float32))
     z2 = mixed / w2[:, None]
     return z2.astype(flat.dtype), w2.astype(w.dtype), pub2
 
@@ -250,8 +252,8 @@ def compressed_stale_mix(flat, w, kept, sent, buf_t0, buf_w0, pub, key,
     wf = w.astype(jnp.float32)
     theta = f * wf[:, None]
     c, pub2 = _ef_encode(theta, pub, sent, key, spec)
-    send_t = sent.astype(jnp.float32) @ pub2
-    send_w = sent.astype(jnp.float32) @ wf
+    send_t = mix_dot(sent.astype(jnp.float32), pub2)
+    send_w = mix_dot(sent.astype(jnp.float32), wf)
     mixed = kept.astype(jnp.float32)[:, None] * theta \
         + buf_t0.astype(jnp.float32)
     w2 = kept.astype(jnp.float32) * wf + buf_w0.astype(jnp.float32)

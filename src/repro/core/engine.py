@@ -290,7 +290,7 @@ from .gossip import (gossip_shift, hier_layout, hier_mix_debiased,
                      hier_mix_schedule, hier_mix_split,
                      hier_stale_mix_apply, mix_matrix, mix_schedule,
                      pushsum_gossip_shard, pushsum_mix_debiased,
-                     shard_map_fn, shift_schedule, stale_mix_apply,
+                     shift_schedule, stale_mix_apply,
                      stale_mix_schedule, stale_mix_split)
 
 BACKENDS = ("loop", "vmap", "shard_map", "async", "hier")
@@ -456,6 +456,14 @@ class FederationEngine:
         assert len(self.step_fns) == n_clients
         self.sample_fn = sample_fn
         self.mix = mix
+        if mesh is not None:
+            # GSPMD-propagated placement: the local phase vmaps unsharded
+            # per-round keys against the client-sharded state, which an
+            # Explicit-typed mesh (jax.make_mesh's default) refuses
+            mesh = jax.sharding.Mesh(
+                mesh.devices, mesh.axis_names,
+                axis_types=(jax.sharding.AxisType.Auto,) * len(
+                    mesh.axis_names))
         self.mesh = mesh
         self.axis = axis
         self.accountants: List = [None] * n_clients
@@ -549,8 +557,6 @@ class FederationEngine:
         self.verify_commitments = bool(getattr(cfg, "verify_commitments",
                                                False))
         self.transmit_tamper: Optional[Callable] = None
-        # donation lets XLA update params/opt in place; CPU only warns
-        self._donate = (0,) if jax.default_backend() != "cpu" else ()
         self._masked_sampler = _sampler_accepts_n_valid(sample_fn)
         self._loop_steps: Dict = {}   # id(step_fn) -> jitted one-step
         self._rounds: Dict = {}       # compile cache: key -> jitted round
@@ -562,6 +568,18 @@ class FederationEngine:
         self._stack_misses = 0        # observability: cache-miss count
 
     # -- state construction / access ---------------------------------------
+
+    def _place(self, stacked, pin=jax.device_put):
+        """The shard_map backend keeps client k's slice of every stacked
+        [K, ...] leaf on device k of the mesh axis; without this the stack
+        sits on the default device and every chip runs every client. The
+        other backends leave placement to JAX. ``pin`` is ``device_put``
+        for arrays and ``with_sharding_constraint`` inside a round program,
+        whose output state stays split the same way."""
+        if self.backend != "shard_map" or stacked is None:
+            return stacked
+        return pin(stacked, jax.sharding.NamedSharding(
+            self.mesh, jax.sharding.PartitionSpec(self.axis)))
 
     def _clients_of(self, state):
         """The per-client state tree (stacked pytree, or a list on the loop
@@ -589,7 +607,7 @@ class FederationEngine:
         states = [self.init_fns[k](jax.random.fold_in(key, k))
                   for k in range(self.K)]
         base: Any = (states if self.backend == "loop"
-                     else stack_states(states))
+                     else self._place(stack_states(states)))
         if not self._wrapped:
             return base
         state: Dict[str, Any] = {"clients": base}
@@ -663,7 +681,10 @@ class FederationEngine:
         per-client unstack), the round counter, per-client accountant step
         counts, and the base RNG key the round keys derive from. The same
         builder produces the restore template, so save and restore always
-        agree on tree structure."""
+        agree on tree structure. The state is copied to the host first:
+        slicing it per client on the device would hold a second copy of
+        every client's state there."""
+        state = jax.device_get(state)
         clients = {f"c{k:04d}": s
                    for k, s in enumerate(self.export_states(state))}
         steps = np.asarray([a.steps if a is not None else 0
@@ -711,26 +732,32 @@ class FederationEngine:
         """Bit-exact inverse of :meth:`save_state`; returns ``(state,
         rounds_done)`` in THIS engine's layout (a loop-backend checkpoint
         restores fine into a vmap engine and vice versa). ``like`` is a
-        template state with the target tree structure (default: a throwaway
-        ``init_states``). Attached accountants get their step counters
-        back; passing the run's ``base_key`` verifies the checkpoint was
-        written under the same key schedule."""
+        template state with the target tree structure (default: the shapes
+        of ``init_states``; only shapes and dtypes are read, through host
+        zeros whose pages are never touched). The snapshot is read to the
+        host and stacked onto the device leaf by leaf, so the device never
+        holds more than the restored state. Attached accountants get their
+        step counters back; passing the run's ``base_key`` verifies the
+        checkpoint was written under the same key schedule."""
         if like is None:
-            like = self.init_states(jax.random.PRNGKey(0))
-        loaded = load_checkpoint(path, self._ckpt_payload(like, 0, None))
+            like = jax.eval_shape(self.init_states, jax.random.PRNGKey(0))
+        template = jax.tree_util.tree_map(
+            lambda x: np.zeros(np.shape(x), x.dtype), like)
+        loaded = load_checkpoint(path, self._ckpt_payload(template, 0, None))
         clients = [loaded["clients"][f"c{k:04d}"] for k in range(self.K)]
-        base: Any = (clients if self.backend == "loop"
-                     else stack_states(clients))
+        base: Any = (jax.tree_util.tree_map(jnp.asarray, clients)
+                     if self.backend == "loop"
+                     else self._place(stack_states(clients)))
         if self._wrapped:
             state: Any = {"clients": base}
             if self._stale:
-                state["stale_theta"] = loaded["stale_theta"]
-                state["stale_w"] = loaded["stale_w"]
+                state["stale_theta"] = jnp.asarray(loaded["stale_theta"])
+                state["stale_w"] = jnp.asarray(loaded["stale_w"])
             if self._hier_stale:
-                state["hier_buffer"] = loaded["hier_buffer"]
-                state["hier_w"] = loaded["hier_w"]
+                state["hier_buffer"] = jnp.asarray(loaded["hier_buffer"])
+                state["hier_w"] = jnp.asarray(loaded["hier_w"])
             if self._compressed:
-                state["ef_state"] = loaded["compress_ef_state"]
+                state["ef_state"] = jnp.asarray(loaded["compress_ef_state"])
         else:
             state = base
         rounds_done = int(loaded["rounds_done"])
@@ -1065,6 +1092,7 @@ class FederationEngine:
                 "are padded and mask-sampled); use backend='loop' for "
                 "genuinely incompatible trees")
         steps = self.client_steps(data)
+        stacked, n_valid = self._place(stacked), self._place(n_valid)
         entry = (data, stacked, n_valid, lengths, steps)  # ref keeps id valid
         self._data_cache[ck] = entry
         self._data_cache.move_to_end(ck)
@@ -1099,10 +1127,11 @@ class FederationEngine:
         per-scan-iteration ``live`` mask: once client k has run its
         ``steps[k]`` local steps its state AND its RNG chain freeze, so it
         sits out the rest of the scan without perturbing either. Uniform-
-        step rounds skip the two per-step full-state selects entirely
-        (inactive clients are reverted once, after the scan, exactly as
-        before), so the common fixed-``local_steps`` configuration pays
-        nothing for ragged support."""
+        step rounds mask by ``active`` alone. The select runs per step, on
+        the scan carry, so XLA updates the state in place: reverting
+        inactive clients after the scan instead would keep the round's
+        input state alive next to the carry, a second copy of every
+        client's parameters and optimizer moments."""
         step_fn, sample, K = self.step_fns[0], self.sample_fn, self.K
         if self._masked_sampler and pass_n_valid:
             def one(state, data_k, nv_k, key):
@@ -1124,10 +1153,9 @@ class FederationEngine:
             def body(carry, i):
                 st, ks = carry
                 st2, ks2, m = jax.vmap(one)(st, data, n_valid, ks)
-                if step_masked:
-                    live = act & (i < steps)
-                    st2 = _tree_where(live, st2, st)  # exhausted/inactive:
-                    ks2 = _tree_where(live, ks2, ks)  # state + RNG frozen
+                live = act & (i < steps) if step_masked else act
+                st2 = _tree_where(live, st2, st)  # exhausted/inactive:
+                ks2 = _tree_where(live, ks2, ks)  # state + RNG frozen
                 return (st2, ks2), m
 
             (trained, _), ms = jax.lax.scan(
@@ -1138,7 +1166,6 @@ class FederationEngine:
             last = jax.tree_util.tree_map(
                 lambda x: x[idx, jnp.arange(K)], ms)
             last = {k: jnp.where(act, v, jnp.nan) for k, v in last.items()}
-            trained = _tree_where(act, trained, stacked)  # dropouts keep state
             return trained, last
 
         return local_fn
@@ -1195,7 +1222,8 @@ class FederationEngine:
                                       key)
                 if mix_op is not None:
                     trained, _ = exchange(trained, P, key, None)
-                return trained, last
+                return self._place(
+                    trained, jax.lax.with_sharding_constraint), last
 
         return round_fn
 
@@ -1285,7 +1313,7 @@ class FederationEngine:
             self._rounds[rkey] = jax.jit(
                 self._stale_round_core(n_steps, mixing, step_masked,
                                        pass_nv),
-                donate_argnums=tuple(range(ndon)) if self._donate else ())
+                donate_argnums=tuple(range(ndon)))
         if mixing:
             kept, sent = self._stale_split(t, act)
         else:  # placeholders, never read
@@ -1365,7 +1393,7 @@ class FederationEngine:
                 ndon = 3
             self._rounds[rkey] = jax.jit(
                 block_fn,
-                donate_argnums=tuple(range(ndon)) if self._donate else ())
+                donate_argnums=tuple(range(ndon)))
         if mixing:
             kepts, sents = stale_mix_schedule(
                 self.mix, t0, T, self.K, self.cfg.topology,
@@ -1486,8 +1514,7 @@ class FederationEngine:
         tau = self.staleness
         rkey = ("hier", n_steps, step_masked, pass_nv, mixing)
         if rkey not in self._rounds:
-            donate = (tuple(range(3)) if tau else (0,)) if self._donate \
-                else ()
+            donate = tuple(range(3)) if tau else (0,)
             self._rounds[rkey] = jax.jit(
                 self._hier_round_core(n_steps, mixing, step_masked,
                                       pass_nv),
@@ -1544,7 +1571,7 @@ class FederationEngine:
                         (blockss, srcs, scales, acts, ts))
                     return st, bt, bw, ms
 
-                donate = tuple(range(3)) if self._donate else ()
+                donate = tuple(range(3))
             else:
                 def block_fn(stacked, data, n_valid, steps, blockss, srcs,
                              scales, acts, ts, base_key):
@@ -1557,7 +1584,7 @@ class FederationEngine:
                     return jax.lax.scan(
                         body, stacked, (blockss, srcs, scales, acts, ts))
 
-                donate = self._donate
+                donate = (0,)
             self._rounds[rkey] = jax.jit(block_fn, donate_argnums=donate)
         if mixing:
             blockss, srcs, scales = hier_mix_schedule(
@@ -1584,9 +1611,12 @@ class FederationEngine:
 
     def _build_round(self, n_steps: int, mix_op, step_masked: bool = False,
                      pass_n_valid: bool = True):
-        """Jitted single-round program (the ``run_round`` fast path)."""
-        donate = self._donate
-        if donate and self._compressed and mix_op is not None:
+        """Jitted single-round program (the ``run_round`` fast path).
+        The state is donated, on every platform, so XLA updates it in
+        place: a caller that reads a state after handing it to a round
+        fails the same way on the CPU as on the chip."""
+        donate = (0,)
+        if self._compressed and mix_op is not None:
             donate = (0, 1)  # stacked state AND the codec state in place
         return jax.jit(self._round_core(n_steps, mix_op, step_masked,
                                         pass_n_valid),
@@ -1608,8 +1638,9 @@ class FederationEngine:
 
         Per-round RNG keys are folded IN-SCAN from the base key
         (``round_key(base_key, t)`` with the runtime ``ts`` round indices),
-        so a blocked run replays the per-round key schedule bit-exactly."""
-        donate = self._donate
+        so a blocked run replays the per-round key schedule bit-exactly.
+        Donation as in :meth:`_build_round`."""
+        donate = (0,)
         if not isinstance(mix_ops, (list, tuple)):
             core = self._round_core(n_steps, mix_ops, step_masked,
                                     pass_n_valid)
@@ -1631,8 +1662,7 @@ class FederationEngine:
                         body, (stacked, ef_state), (Ps, acts, ts))
                     return st, r, ms
 
-                if donate:
-                    donate = (0, 1)
+                donate = (0, 1)
             else:
                 def block_fn(stacked, data, n_valid, steps, Ps, acts, ts,
                              base_key):
@@ -1686,10 +1716,11 @@ class FederationEngine:
         happens here so the mix_op contract matches the matmul path."""
         topo, sw = self._mix_topology()
         spec = jax.sharding.PartitionSpec(self.axis)
-        gossip_sm = shard_map_fn(
+        gossip_sm = jax.shard_map(
             lambda f, w: pushsum_gossip_shard(
                 f, w, t, self.axis, self.K, topo, sw, active=act_key),
-            self.mesh, in_specs=(spec, spec), out_specs=(spec, spec))
+            mesh=self.mesh, in_specs=(spec, spec), out_specs=(spec, spec),
+            check_vma=False)
 
         def op(flat, w, P):
             mixed, w2 = gossip_sm(flat, w)

@@ -25,6 +25,13 @@ import jax.numpy as jnp
 import numpy as np
 
 
+def mix_dot(a: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
+    """An exchange matmul at full f32 precision. A TPU's default precision
+    for f32 operands is one bf16 pass, which would round every mixed proxy
+    to bf16 in every round."""
+    return jnp.matmul(a, b, precision=jax.lax.Precision.HIGHEST)
+
+
 def exponential_offsets(n_clients: int) -> List[int]:
     """Peer offsets 2^0, 2^1, ..., 2^⌊log2(K-1)⌋ (Assran et al. 2019)."""
     if n_clients <= 1:
@@ -103,7 +110,7 @@ def pushsum_mix(thetas: jnp.ndarray, weights: jnp.ndarray, P: jnp.ndarray,
         return fused_pushsum_mix(thetas, weights, P, debias=False,
                                  interpret=interpret)
     P = jnp.asarray(P, thetas.dtype)
-    return P @ thetas, P.astype(weights.dtype) @ weights
+    return mix_dot(P, thetas), mix_dot(P.astype(weights.dtype), weights)
 
 
 def pushsum_mix_debiased(thetas: jnp.ndarray, weights: jnp.ndarray,
@@ -136,8 +143,8 @@ def pushsum_mix_debiased(thetas: jnp.ndarray, weights: jnp.ndarray,
         from ..kernels.pushsum_mix import fused_pushsum_mix
         return fused_pushsum_mix(thetas, weights, P, debias=True,
                                  interpret=interpret)
-    mixed = jnp.asarray(P, thetas.dtype) @ thetas
-    w2 = jnp.asarray(P, weights.dtype) @ weights
+    mixed = mix_dot(jnp.asarray(P, thetas.dtype), thetas)
+    w2 = mix_dot(jnp.asarray(P, weights.dtype), weights)
     return mixed / w2[:, None], w2
 
 
@@ -170,8 +177,8 @@ def stale_mix_apply(flat: jnp.ndarray, w: jnp.ndarray, kept: jnp.ndarray,
         return fused_stale_mix(flat, w, kept, sent, buf_t0, buf_w0,
                                interpret=interpret)
     theta = flat * w[:, None]                  # raw PushSum numerator
-    send_t = sent.astype(flat.dtype) @ theta
-    send_w = sent.astype(w.dtype) @ w
+    send_t = mix_dot(sent.astype(flat.dtype), theta)
+    send_w = mix_dot(sent.astype(w.dtype), w)
     mixed = kept.astype(flat.dtype)[:, None] * theta + buf_t0
     w2 = kept.astype(w.dtype) * w + buf_w0
     return mixed / w2[:, None], send_t, w2, send_w
@@ -509,8 +516,8 @@ def _hier_intra(x, w, blocks, use_pallas, interpret):
             f, ww, p, debias=False, interpret=interpret))(xs, ws, blocks)
     else:
         Pb = jnp.asarray(blocks, x.dtype)
-        mixed = jnp.einsum("sij,sjd->sid", Pb, xs)
-        wm = jnp.einsum("sij,sj->si", Pb.astype(w.dtype), ws)
+        mixed = mix_dot(Pb, xs)
+        wm = mix_dot(Pb.astype(w.dtype), ws[..., None])[..., 0]
     return mixed.reshape(x.shape), wm.reshape(w.shape)
 
 
@@ -604,17 +611,6 @@ def hier_gossip_reference(z0, w0, Ps, n_shards: int, staleness: int = 0):
 
 # ---------------------------------------------------------------------------
 # distributed backend: one client per mesh-axis index, ppermute exchange
-
-
-def shard_map_fn(f, mesh, in_specs, out_specs):
-    """Version-portable ``shard_map`` (jax>=0.5 exposes ``jax.shard_map``;
-    0.4.x only has the experimental entry point with ``check_rep``)."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map as _sm
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=False)
 
 
 def pushsum_gossip_shard(theta_local: jnp.ndarray, w_local: jnp.ndarray,

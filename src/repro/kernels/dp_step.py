@@ -33,14 +33,12 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from . import resolve_interpret
-
-
-def _pad1(x, pad):
-    return jnp.pad(x, (0, pad)) if pad else x
+from .dp_clip import LANES, row_layout, row_spec, to_rows
 
 
 def _sgd_kernel(sc_ref, acc_ref, noise_ref, p_ref, p2_ref):
-    stddev, n_units, lr, wd = (sc_ref[0], sc_ref[1], sc_ref[2], sc_ref[3])
+    stddev, n_units, lr, wd = (sc_ref[0, 0], sc_ref[0, 1], sc_ref[0, 2],
+                               sc_ref[0, 3])
     g = (acc_ref[...] + stddev * noise_ref[...]) / n_units
     p = p_ref[...].astype(jnp.float32)
     g = g + wd * p
@@ -54,31 +52,25 @@ def noise_sgd_step(acc: jnp.ndarray, noise: jnp.ndarray, p: jnp.ndarray, *,
     """Fused noise-add + clipped-mean + SGD step over 1-D flat vectors:
     ``p − lr·((acc + stddev·noise)/n_units + weight_decay·p)``."""
     n = acc.shape[0]
-    b = min(block, max(n, 1))
-    n_blocks = -(-n // b)
-    pad = n_blocks * b - n
+    br, n_blocks = row_layout(n, block)
     sc = jnp.stack([jnp.asarray(s, jnp.float32)
-                    for s in (stddev, n_units, lr, weight_decay)])
+                    for s in (stddev, n_units, lr, weight_decay)])[None]
     out = pl.pallas_call(
         _sgd_kernel,
         grid=(n_blocks,),
-        in_specs=[
-            pl.BlockSpec(memory_space=pltpu.SMEM),  # scalars
-            pl.BlockSpec((b,), lambda i: (i,)),
-            pl.BlockSpec((b,), lambda i: (i,)),
-            pl.BlockSpec((b,), lambda i: (i,)),
-        ],
-        out_specs=pl.BlockSpec((b,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct((n_blocks * b,), p.dtype),
+        in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM)]  # [1, n] scalars
+        + [row_spec(br)] * 3,
+        out_specs=row_spec(br),
+        out_shape=jax.ShapeDtypeStruct((n_blocks * br, LANES), p.dtype),
         interpret=resolve_interpret(interpret),
-    )(sc, _pad1(acc, pad), _pad1(noise, pad), _pad1(p, pad))
-    return out[:n]
+    )(sc, *(to_rows(x, br, n_blocks) for x in (acc, noise, p)))
+    return out.reshape(-1)[:n]
 
 
 def _adam_kernel(b1, b2, eps, sc_ref, acc_ref, noise_ref, p_ref, m_ref,
                  v_ref, p2_ref, m2_ref, v2_ref):
-    stddev, n_units, lr = sc_ref[0], sc_ref[1], sc_ref[2]
-    wd, c1, c2 = sc_ref[3], sc_ref[4], sc_ref[5]
+    stddev, n_units, lr = sc_ref[0, 0], sc_ref[0, 1], sc_ref[0, 2]
+    wd, c1, c2 = sc_ref[0, 3], sc_ref[0, 4], sc_ref[0, 5]
     g = (acc_ref[...] + stddev * noise_ref[...]) / n_units
     p = p_ref[...].astype(jnp.float32)
     g = g + wd * p
@@ -107,29 +99,17 @@ def noise_adam_step(acc: jnp.ndarray, noise: jnp.ndarray, p: jnp.ndarray,
     mean gradient ``(acc + stddev·noise)/n_units (+ weight_decay·p)``."""
     assert c1 is not None and c2 is not None, "pass bias corrections c1/c2"
     n = acc.shape[0]
-    b = min(block, max(n, 1))
-    n_blocks = -(-n // b)
-    pad = n_blocks * b - n
+    br, n_blocks = row_layout(n, block)
     sc = jnp.stack([jnp.asarray(s, jnp.float32)
-                    for s in (stddev, n_units, lr, weight_decay, c1, c2)])
-    p2, m2, v2 = pl.pallas_call(
+                    for s in (stddev, n_units, lr, weight_decay, c1, c2)])[None]
+    outs = pl.pallas_call(
         functools.partial(_adam_kernel, float(b1), float(b2), float(eps)),
         grid=(n_blocks,),
-        in_specs=[
-            pl.BlockSpec(memory_space=pltpu.SMEM),  # scalars
-            pl.BlockSpec((b,), lambda i: (i,)),
-            pl.BlockSpec((b,), lambda i: (i,)),
-            pl.BlockSpec((b,), lambda i: (i,)),
-            pl.BlockSpec((b,), lambda i: (i,)),
-            pl.BlockSpec((b,), lambda i: (i,)),
-        ],
-        out_specs=(pl.BlockSpec((b,), lambda i: (i,)),
-                   pl.BlockSpec((b,), lambda i: (i,)),
-                   pl.BlockSpec((b,), lambda i: (i,))),
-        out_shape=(jax.ShapeDtypeStruct((n_blocks * b,), p.dtype),
-                   jax.ShapeDtypeStruct((n_blocks * b,), m.dtype),
-                   jax.ShapeDtypeStruct((n_blocks * b,), v.dtype)),
+        in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM)]  # [1, n] scalars
+        + [row_spec(br)] * 5,
+        out_specs=(row_spec(br),) * 3,
+        out_shape=tuple(jax.ShapeDtypeStruct((n_blocks * br, LANES), x.dtype)
+                        for x in (p, m, v)),
         interpret=resolve_interpret(interpret),
-    )(sc, _pad1(acc, pad), _pad1(noise, pad), _pad1(p, pad), _pad1(m, pad),
-      _pad1(v, pad))
-    return p2[:n], m2[:n], v2[:n]
+    )(sc, *(to_rows(x, br, n_blocks) for x in (acc, noise, p, m, v)))
+    return tuple(x.reshape(-1)[:n] for x in outs)

@@ -18,7 +18,9 @@ HBM→VMEM exactly once per round:
 
 Accumulation is f32 (``preferred_element_type``) regardless of the input
 dtype; the [K]-sized weight reductions are computed outside the kernel
-(they are O(K), not O(K·D)). Numeric contract: allclose to the plain-XLA
+(they are O(K), not O(K·D)). The chunk width shrinks with K
+(:func:`mix_block`) so that the double-buffered [K, b] blocks fit the
+scoped VMEM at any cohort size. Numeric contract: allclose to the plain-XLA
 chain (same math, different reduction order) — pinned by the ``use_pallas``
 columns of tests/test_conformance.py and the ``ref.py`` oracle sweeps.
 """
@@ -33,10 +35,30 @@ from jax.experimental import pallas as pl
 
 from . import resolve_interpret
 
+#: scoped-VMEM bytes the streamed [K, b] blocks and their f32 temporaries
+#: may take: half of the 16 MiB default scoped limit of a TPU v5e core,
+#: leaving the rest to the resident [K, K] matrix and Mosaic's own scratch
+VMEM_BLOCK_BUDGET = 8 * 2 ** 20
+
+#: f32 matmuls at full precision (a TPU's default is one bf16 pass)
+_HIGHEST = jax.lax.Precision.HIGHEST
+
+
+def mix_block(K: int, itemsize: int, n_streams: int, n_temps: int,
+              block: int) -> int:
+    """Chunk width ``b`` of a [K, D] mix kernel: at most ``block``, and the
+    widest multiple of 128 whose ``n_streams`` double-buffered [K, b]
+    input/output blocks plus ``n_temps`` f32 [K, b] temporaries fit
+    :data:`VMEM_BLOCK_BUDGET` (8192 up to K≈40 for f32, 1280 at K=256)."""
+    per_col = K * (2 * n_streams * itemsize + 4 * n_temps)
+    fit = max(128, VMEM_BLOCK_BUDGET // per_col // 128 * 128)
+    return min(block, fit)
+
 
 def _mix_kernel(debias: bool, P_ref, w2_ref, x_ref, o_ref):
     x = x_ref[...].astype(jnp.float32)                       # [K, b]
-    mixed = jnp.dot(P_ref[...], x, preferred_element_type=jnp.float32)
+    mixed = jnp.dot(P_ref[...], x, preferred_element_type=jnp.float32,
+                    precision=_HIGHEST)
     if debias:
         mixed = mixed / w2_ref[...][:, None]
     o_ref[...] = mixed.astype(o_ref.dtype)
@@ -55,8 +77,8 @@ def fused_pushsum_mix(flat: jnp.ndarray, w: jnp.ndarray, P: jnp.ndarray, *,
     resident in VMEM across the D-grid; w' is O(K) and computed outside."""
     K, D = flat.shape
     Pf = jnp.asarray(P, jnp.float32)
-    w2 = Pf @ w.astype(jnp.float32)
-    b = min(block, max(D, 1))
+    w2 = jnp.matmul(Pf, w.astype(jnp.float32), precision=_HIGHEST)
+    b = min(mix_block(K, flat.dtype.itemsize, 2, 2, block), max(D, 1))
     n_blocks = -(-D // b)
     pad = n_blocks * b - D
     x = jnp.pad(flat, ((0, 0), (0, pad))) if pad else flat
@@ -78,8 +100,8 @@ def fused_pushsum_mix(flat: jnp.ndarray, w: jnp.ndarray, P: jnp.ndarray, *,
 def _stale_kernel(w_ref, kept_ref, sent_ref, w2_ref, x_ref, buf_ref,
                   z_ref, send_ref):
     theta = x_ref[...].astype(jnp.float32) * w_ref[...][:, None]  # re-bias
-    send = jnp.dot(sent_ref[...], theta,
-                   preferred_element_type=jnp.float32)
+    send = jnp.dot(sent_ref[...], theta, preferred_element_type=jnp.float32,
+                   precision=_HIGHEST)
     mixed = kept_ref[...][:, None] * theta + buf_ref[...].astype(jnp.float32)
     z_ref[...] = (mixed / w2_ref[...][:, None]).astype(z_ref.dtype)
     send_ref[...] = send.astype(send_ref.dtype)
@@ -106,8 +128,8 @@ def fused_stale_mix(flat: jnp.ndarray, w: jnp.ndarray, kept: jnp.ndarray,
     keptf = kept.astype(jnp.float32)
     sentf = sent.astype(jnp.float32)
     w2 = keptf * wf + buf_w0.astype(jnp.float32)
-    send_w = sentf @ wf
-    b = min(block, max(D, 1))
+    send_w = jnp.matmul(sentf, wf, precision=_HIGHEST)
+    b = min(mix_block(K, flat.dtype.itemsize, 4, 4, block), max(D, 1))
     n_blocks = -(-D // b)
     pad = n_blocks * b - D
     x, buf = flat, buf_t0

@@ -48,7 +48,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from ..configs import INPUT_SHAPES, get_config, list_archs
 from ..configs.base import DPConfig, InputShape, ModelConfig, ProxyFLConfig
 from ..configs.registry import proxy_of
-from .mesh import TPU_V5E, make_production_mesh, mesh_context
+from .mesh import TPU_V5E, make_production_mesh
 from .sharding import named
 from .steps import (
     StepOptions,
@@ -291,7 +291,7 @@ def run_one(arch: str, shape_name: str, mesh_kind: str, *,
                          + sharded_bytes_per_device(batch_sds, batch_spec, mesh))
         mf = model_flops(cfg, shape, None)
 
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         lowered = jitted.lower(*args)
         t_lower = time.time() - t0
         compiled = lowered.compile()

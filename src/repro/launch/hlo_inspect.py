@@ -105,7 +105,7 @@ def main(argv=None) -> int:
     from ..configs import INPUT_SHAPES, get_config, list_archs
     from ..configs.base import DPConfig, ProxyFLConfig
     from ..configs.registry import proxy_of
-    from .mesh import make_production_mesh, mesh_context
+    from .mesh import make_production_mesh
     from .sharding import named
     from .steps import (input_specs, make_decode_step,
                         make_prefill_step, make_train_step, serve_shardings,
@@ -173,7 +173,7 @@ def main(argv=None) -> int:
             out_shardings=(named(state_spec, mesh), None), donate_argnums=(0,))
         args_ = (state_sds, batch_sds)
 
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         txt = jitted.lower(*args_).compile().as_text()
     analyze(txt, top=args.top)
     return 0

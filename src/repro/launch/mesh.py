@@ -27,17 +27,6 @@ TPU_V5E = {
 }
 
 
-def mesh_context(mesh):
-    """Context manager that installs ``mesh`` as the ambient mesh.
-
-    ``jax.set_mesh`` landed after the 0.4.x series; on older jax the Mesh
-    object itself is the context manager (it pushes onto the resource-env
-    stack), so fall back to returning ``mesh`` directly.
-    """
-    set_mesh = getattr(jax, "set_mesh", None)
-    return set_mesh(mesh) if set_mesh is not None else mesh
-
-
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")

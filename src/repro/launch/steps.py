@@ -35,7 +35,7 @@ from jax.sharding import PartitionSpec as P
 
 from ..configs.base import InputShape, ModelConfig, ProxyFLConfig
 from ..core.dp import dp_gradient_chunked, non_dp_gradient
-from ..core.gossip import gossip_shift, hier_mix_schedule, shard_map_fn
+from ..core.gossip import gossip_shift, hier_mix_schedule
 from ..nn.losses import dml_loss
 from ..nn.model import forward, init_cache, init_model
 from ..nn.modules import tree_flatten_vector, tree_unflatten_vector
@@ -296,10 +296,10 @@ def make_fl_round_step(cfg_priv: ModelConfig, cfg_proxy: ModelConfig,
         recv_w = jax.lax.ppermute(send_w, "pod", perm)
         return self_w * flat + recv_f, self_w * w + recv_w
 
-    gossip_sm = shard_map_fn(
-        gossip, mesh,
+    gossip_sm = jax.shard_map(
+        gossip, mesh=mesh,
         in_specs=(P("pod"), P("pod")),
-        out_specs=(P("pod"), P("pod")))
+        out_specs=(P("pod"), P("pod")), check_vma=False)
 
     def round_step(stacked_state, stacked_batch, keys):
         # local DML on every client in parallel (clients stacked on "pod")
@@ -409,9 +409,9 @@ def make_hier_round_block_step(cfg_priv: ModelConfig, cfg_proxy: ModelConfig,
             # rows were already mixed by the block matmul above)
             return intra + sc[:, None] * rx, wm + sc * rw
 
-        sm = shard_map_fn(body, mesh,
-                          in_specs=(P("pod"), P("pod"), P("pod"), P("pod")),
-                          out_specs=(P("pod"), P("pod")))
+        sm = jax.shard_map(body, mesh=mesh,
+                           in_specs=(P("pod"), P("pod"), P("pod"), P("pod")),
+                           out_specs=(P("pod"), P("pod")), check_vma=False)
         return lambda flat, w: sm(flat, w, blocks0, scale0)
 
     exchanges = [make_exchange(t0 + i) for i in range(n_rounds)]

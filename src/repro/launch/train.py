@@ -48,8 +48,9 @@ Examples::
 from __future__ import annotations
 
 import argparse
+import functools
 import time
-from typing import List
+from typing import Any, Dict, List
 
 import jax
 import jax.numpy as jnp
@@ -64,6 +65,7 @@ from ..core.engine import FederationEngine, block_spans
 from ..data.synthetic import make_lm_data
 from ..nn.losses import cross_entropy
 from ..nn.model import forward
+from .compile_cache import use_compile_cache
 from .steps import StepOptions, init_train_state, make_train_step
 
 
@@ -88,17 +90,59 @@ def build_cfgs(args):
     return cfg, proxy
 
 
+@functools.lru_cache(maxsize=4)
+def _ppl_loss(cfg: ModelConfig):
+    """One jitted eval loss per config (a fresh jit per call would compile
+    the private forward again at every block edge)."""
+    return jax.jit(lambda p, t: cross_entropy(
+        forward(p, cfg, t[:, :-1])[0], t[:, 1:]))
+
+
 def evaluate_ppl(params, cfg: ModelConfig, tokens: jnp.ndarray, batch: int = 8
                  ) -> float:
     losses = []
-    fwd = jax.jit(lambda p, t: cross_entropy(
-        forward(p, cfg, t[:, :-1])[0], t[:, 1:]))
+    fwd = _ppl_loss(cfg)
     for i in range(0, tokens.shape[0], batch):
         losses.append(float(fwd(params, tokens[i:i + batch])))
     return float(np.exp(np.mean(losses)))
 
 
+def make_engine(cfg: ModelConfig, proxy: ModelConfig, fl: ProxyFLConfig,
+                backend: str = "vmap", mesh=None) -> FederationEngine:
+    """The federation this driver trains: ``fl.n_clients`` clients of
+    private ``cfg`` + proxy ``proxy`` LM pairs, each step drawing
+    ``fl.batch_size`` sequences from its client's ``[n, seq + 1]`` token
+    array."""
+    # remat: at the 100m preset 4 clients need 12.7 MB more than a 16 GB
+    # v5e holds without it (tests/test_tpu_compile.py compiles this)
+    opts = StepOptions(remat=True, accum=1, dp_chunk=fl.batch_size)
+
+    def sample(toks, kb, n_valid=None):
+        # masked-sampler protocol: ragged per-client corpora on the vmap
+        # backend pass the true sequence count so padding is never drawn
+        hi = toks.shape[0] if n_valid is None else n_valid
+        idx = jax.random.randint(kb, (fl.batch_size,), 0, hi)
+        return {"tokens": toks[idx, :-1], "labels": toks[idx, 1:]}
+
+    return FederationEngine(
+        fl, n_clients=fl.n_clients,
+        step_fns=make_train_step(cfg, proxy, fl, opts),
+        init_fns=lambda k2: init_train_state(k2, cfg, proxy, fl, opts),
+        sample_fn=sample, backend=backend, mix="pushsum", mesh=mesh)
+
+
 def main(argv=None) -> int:
+    use_compile_cache()
+    run(argv)
+    return 0
+
+
+def run(argv=None) -> Dict[str, Any]:
+    """Parse ``argv`` as the command line, train, print one line per round,
+    and return the run: ``engine``, final ``state``, the stacked per-round
+    ``metrics`` of every block (``[rounds, K]`` each, rounds run by this
+    call only) and ``block_seconds`` (device time of each block, compile
+    included in the first)."""
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--arch", choices=list_archs())
     ap.add_argument("--preset", choices=("100m",), default=None)
@@ -118,14 +162,15 @@ def main(argv=None) -> int:
     ap.add_argument("--topology", default="exponential",
                     choices=("exponential", "ring", "full"))
     ap.add_argument("--backend", default="vmap",
-                    choices=("loop", "vmap", "async", "hier"),
+                    choices=("loop", "vmap", "shard_map", "async", "hier"),
                     help="federation engine backend (vmap = one compiled "
-                         "round program; async = staleness-τ stale gossip, "
+                         "round program; shard_map = one client per device "
+                         "of the first --clients devices, ppermute "
+                         "exchange; async = staleness-τ stale gossip, "
                          "see --staleness; hier = two-level cohort of "
                          "--n-shards shards with block-diagonal intra-shard "
                          "mixing and sparse cross-shard edges, see "
-                         "--n-shards; shard_map needs a multi-device "
-                         "mesh, see dryrun.py)")
+                         "--n-shards)")
     ap.add_argument("--staleness", type=int, default=0,
                     help="gossip delay τ for --backend async or hier: the "
                          "round-t exchange merges neighbor proxy mass sent "
@@ -216,8 +261,6 @@ def main(argv=None) -> int:
     if args.n_shards > 1 and args.backend != "hier":
         raise SystemExit("--n-shards > 1 requires --backend hier "
                          "(the flat backends have no shard level)")
-    opts = StepOptions(remat=False, accum=1, dp_chunk=args.batch)
-
     key = jax.random.PRNGKey(args.seed)
     print(f"[train] private={cfg.name} ({tree_size_of(cfg)} params approx: "
           f"{cfg.param_counts()['total']/1e6:.1f}M)  proxy={proxy.name} "
@@ -240,18 +283,14 @@ def main(argv=None) -> int:
         lm_set(jax.random.fold_in(key, 999 + k), max(1, 32 // K), domain=k)
         for k in range(K)])
 
-    def sample(toks, kb, n_valid=None):
-        # masked-sampler protocol: ragged per-client corpora on the vmap
-        # backend pass the true sequence count so padding is never drawn
-        hi = toks.shape[0] if n_valid is None else n_valid
-        idx = jax.random.randint(kb, (args.batch,), 0, hi)
-        return {"tokens": toks[idx, :-1], "labels": toks[idx, 1:]}
-
-    engine = FederationEngine(
-        fl, n_clients=K,
-        step_fns=make_train_step(cfg, proxy, fl, opts),
-        init_fns=lambda k2: init_train_state(k2, cfg, proxy, fl, opts),
-        sample_fn=sample, backend=args.backend, mix="pushsum")
+    mesh = None
+    if args.backend == "shard_map":
+        if jax.device_count() < K:
+            raise SystemExit(f"--backend shard_map needs one device per "
+                             f"client: {K} clients, {jax.device_count()} "
+                             "devices")
+        mesh = jax.make_mesh((K,), ("clients",), devices=jax.devices()[:K])
+    engine = make_engine(cfg, proxy, fl, args.backend, mesh)
     if not args.no_dp:
         # DP sample rate q = B / n_local from each client's ACTUAL dataset
         # size (the accountant's subsampling amplification assumes this).
@@ -259,10 +298,8 @@ def main(argv=None) -> int:
             PrivacyAccountant(args.sigma,
                               min(1.0, args.batch / data[k].shape[0]), 1e-5)
             for k in range(K)])
-    state = engine.init_states(key)
 
-    ckpt = None
-    start = 0
+    ckpt, state, start = None, None, 0
     if args.checkpoint_dir:
         ckpt = FederationCheckpointer(
             args.checkpoint_dir, every=args.checkpoint_every,
@@ -273,24 +310,33 @@ def main(argv=None) -> int:
                 size_skew=args.size_skew),
             verify=fl.verify_commitments)
         if args.resume:
-            restored = ckpt.restore_latest(engine, like=state, base_key=key)
+            # restored before any init: one client state on the device
+            restored = ckpt.restore_latest(engine, base_key=key)
             if restored is not None:
                 state, start = restored
                 print(f"[train] resumed from {args.checkpoint_dir} at "
                       f"round {start}")
+    if state is None:
+        state = engine.init_states(key)
 
     # engine-owned round-blocks: up to --rounds-per-block rounds run as one
     # compiled program; the host syncs (checkpoint, ppl eval, logging) only
     # at block edges, and block_spans cuts blocks so every checkpoint-
     # cadence round IS a block edge — the snapshot set matches per-round
     # execution.
+    rows: Dict[str, List[np.ndarray]] = {}
+    block_seconds: List[float] = []
     for t, n_block in block_spans(start, args.rounds, args.rounds_per_block,
                                   ckpt.every if ckpt is not None else 0):
-        t0 = time.time()
+        t0 = time.perf_counter()
         state, metrics = engine.run_rounds(state, data, t, n_block, key)
+        jax.block_until_ready(state)  # device time, not the enqueue
+        dt = time.perf_counter() - t0
+        block_seconds.append(dt)
+        for name, v in metrics.items():
+            rows.setdefault(name, []).append(v)
         if ckpt is not None:
             ckpt.maybe_save(engine, state, t + n_block - 1, base_key=key)
-        dt = time.time() - t0
         ppl = evaluate_ppl(engine.client_params(state, 0, "private"), cfg, test)
         # worst case over clients: under --size-skew the smallest client has
         # the largest sample rate and spends epsilon fastest
@@ -305,7 +351,8 @@ def main(argv=None) -> int:
             if i == n_block - 1:  # block edge: host-synced ppl/eps/time
                 line += f"client0_test_ppl={ppl:.2f} eps={eps:.3f} ({dt:.1f}s)"
             print(line)
-    return 0
+    return {"engine": engine, "state": state, "block_seconds": block_seconds,
+            "metrics": {k: np.concatenate(v) for k, v in rows.items()}}
 
 
 def tree_size_of(cfg: ModelConfig) -> str:

@@ -13,15 +13,9 @@ from repro.configs.registry import proxy_of
 from repro.launch.sharding import (batch_pspec, cache_pspecs, choose_mode,
                                    param_pspec, tree_pspecs)
 
-def _abstract_mesh(sizes, names):
-    try:  # jax >= 0.5: AbstractMesh(axis_sizes, axis_names)
-        return jax.sharding.AbstractMesh(sizes, names)
-    except TypeError:  # jax 0.4.x: AbstractMesh(((name, size), ...))
-        return jax.sharding.AbstractMesh(tuple(zip(names, sizes)))
 
-
-MESH = _abstract_mesh((16, 16), ("data", "model"))
-MESH3 = _abstract_mesh((2, 16, 16), ("pod", "data", "model"))
+MESH = jax.sharding.AbstractMesh((16, 16), ("data", "model"))
+MESH3 = jax.sharding.AbstractMesh((2, 16, 16), ("pod", "data", "model"))
 SIZES = {"data": 16, "model": 16}
 
 

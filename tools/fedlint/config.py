@@ -92,7 +92,7 @@ RNG_ALLOWED_SITES: Tuple[Allow, ...] = (
     Allow("src/repro/core/baselines.py", "run_federated", ("PRNGKey", "key"),
           "the run's base key from the user seed; rounds derive via "
           "round_key"),
-    Allow("src/repro/launch/train.py", "main",
+    Allow("src/repro/launch/train.py", "run",
           ("PRNGKey", "key", "fold_in"),
           "driver root key + per-client dataset streams fold_in(key, "
           "100+k)/fold_in(key, 999+k), outside the engine's fold domains"),

@@ -38,7 +38,7 @@ _TRANSFORMS = {
     "jax.lax.scan", "jax.lax.map", "jax.lax.while_loop", "jax.lax.cond",
     "jax.lax.fori_loop", "jax.lax.switch", "jax.lax.associative_scan",
     "jax.experimental.pallas.pallas_call",
-    "jax.experimental.shard_map.shard_map",
+    "jax.shard_map",
 }
 
 # attribute chains that yield static (python-int) values even on tracers;
