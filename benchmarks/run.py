@@ -21,11 +21,9 @@ from . import (fig3_accuracy, fig4_comm, fig5_ablations, fig6_kvasir,
 # name -> (module, paper anchor, runtime tier). The one-line description
 # shown by ``--list`` is each module's own docstring first line, so
 # registry and docs cannot drift apart; tests assert every fig_* file on
-# disk is here. The TIER is the CI contract: "fast" figures finish in CPU
-# minutes at default settings and are run by the non-gating baseline step
-# (scripts/bench_baseline.py selects them FROM THIS FIELD — CI never
-# hard-codes module names); "full" figures are accuracy sweeps that only
-# make sense at paper scale.
+# disk is here. The TIER selects figures for ``--tier``: "fast" figures
+# finish in CPU minutes at default settings; "full" figures are accuracy
+# sweeps that only make sense at paper scale.
 MODULES = {
     "fig3_accuracy": (fig3_accuracy, "Fig. 3 / Fig. 9", "full"),
     "fig4_comm": (fig4_comm, "Fig. 4 / Fig. 13", "full"),
@@ -48,8 +46,8 @@ TIERS = ("fast", "full")
 
 
 def names_for_tier(tier: str) -> list:
-    """Registry names whose runtime tier is ``tier`` — the programmatic
-    hook CI slices use instead of hard-coding module names."""
+    """Registry names whose runtime tier is ``tier``, as ``--tier``
+    selects them."""
     if tier not in TIERS:
         raise ValueError(f"unknown tier {tier!r}; expected one of {TIERS}")
     return [n for n, (_, _, t) in MODULES.items() if t == tier]
@@ -75,8 +73,7 @@ def main(argv=None) -> int:
                     help="print every registered benchmark with its "
                          "one-line description and runtime tier, and exit")
     ap.add_argument("--tier", choices=TIERS, default="",
-                    help="run only benchmarks of this runtime tier (CI's "
-                         "non-gating baseline step uses --tier fast)")
+                    help="run only benchmarks of this runtime tier")
     ap.add_argument("--full", action="store_true", help="paper-scale settings")
     args = ap.parse_args(argv)
     if args.list:

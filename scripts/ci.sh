@@ -32,15 +32,6 @@
 #                             # stale mix, noise→SGD/Adam step vs the ref
 #                             # oracles) so every PR exercises every compiled
 #                             # path including the fused kernels
-#   scripts/ci.sh --bench     # NON-GATING perf baseline: the fast-tier
-#                             # benchmark figures (selected from the
-#                             # benchmarks.run registry's tier field — no
-#                             # module names hard-coded here) write the
-#                             # schema-stable BENCH_9.json artifact at the
-#                             # repo root for CI to archive; a failure
-#                             # prints a banner but NEVER fails the job
-#                             # (shared runners make wall-clock gates
-#                             # flaky by construction)
 #   scripts/ci.sh --smoke     # resume-correctness smoke: 4-client federation
 #                             # killed after round 2 of 3 and resumed (per-
 #                             # round, rounds_per_block=2 kill-after-block,
@@ -89,16 +80,6 @@ if [[ "${1:-}" == "--lint" ]]; then
 elif [[ "${1:-}" == "--fast" ]]; then
   MARK="-m fast"
   shift
-elif [[ "${1:-}" == "--bench" ]]; then
-  shift
-  echo "== bench baseline (non-gating): fast-tier figures -> BENCH_9.json =="
-  if python scripts/bench_baseline.py "$@"; then
-    echo "== bench baseline artifact written: BENCH_9.json =="
-  else
-    echo "== bench baseline FAILED — non-gating, job continues ==" >&2
-  fi
-  echo "CI OK"
-  exit 0
 elif [[ "${1:-}" == "--smoke" ]]; then
   shift
   echo "== smoke: checkpoint/resume bit-identity (round-blocks + async-τ2 + hier-τ2) + commitment verify-after-resume / refuse-after-bitflip =="

@@ -24,6 +24,8 @@ import math
 from dataclasses import dataclass, field
 from typing import List, Sequence
 
+from jax.profiler import TraceAnnotation
+
 DEFAULT_ALPHAS: List[int] = list(range(2, 65)) + [96, 128, 256, 512]
 
 
@@ -94,9 +96,10 @@ class PrivacyAccountant:
         self.steps += n
 
     def epsilon(self, delta: float | None = None) -> float:
-        delta = self.delta if delta is None else delta
-        rdp = [r * self.steps for r in self._per_step_rdp]
-        return rdp_to_eps(rdp, self.alphas, delta)
+        with TraceAnnotation("fl.edge.epsilon"):
+            delta = self.delta if delta is None else delta
+            rdp = [r * self.steps for r in self._per_step_rdp]
+            return rdp_to_eps(rdp, self.alphas, delta)
 
     def exceeds(self, budget: float) -> bool:
         return self.epsilon() > budget

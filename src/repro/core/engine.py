@@ -868,8 +868,14 @@ class FederationEngine:
         block = (self._rounds_block_stale if self._stale else
                  self._rounds_block_hier if self._hier else
                  self._rounds_block)
-        return block(state, data, t0, n_rounds, key,
-                     active_schedule(t0, n_rounds, self.K, self.cfg))
+        # host span: everything before the block's device work can start
+        # (schedules, uploads, the enqueue), ending before the metrics'
+        # device-to-host copy waits on the block
+        with jax.profiler.TraceAnnotation("fl.dispatch"):
+            state, ms, act_stack = block(
+                state, data, t0, n_rounds, key,
+                active_schedule(t0, n_rounds, self.K, self.cfg))
+        return state, self._finish_block(ms, act_stack, data)
 
     def _finish_block(self, ms, act_stack, data):
         """Shared block epilogue: pull the stacked [T, K] metrics to host
@@ -927,7 +933,7 @@ class FederationEngine:
                 jnp.asarray(act_stack), ts, key)
             state = ({"clients": clients, "ef_state": state["ef_state"]}
                      if self._compressed else clients)
-        return state, self._finish_block(ms, act_stack, data)
+        return state, ms, act_stack
 
     # -- loop backend --------------------------------------------------------
 
@@ -1146,6 +1152,7 @@ class FederationEngine:
                 state, m = step_fn(state, batch, kn)
                 return state, key, m
 
+        @jax.named_scope("fl.local")
         def local_fn(stacked, data, n_valid, steps, act, key):
             keys = jax.vmap(lambda i: jax.random.fold_in(key, i))(
                 jnp.arange(K, dtype=jnp.uint32))
@@ -1193,6 +1200,7 @@ class FederationEngine:
         local = self._local_phase(n_steps, step_masked, pass_n_valid)
         compressed = self._compressed and mix_op is not None
 
+        @jax.named_scope("fl.exchange")
         def exchange(trained, P, key, ef_state):
             theta = trained["proxy"]["params"]
             like = jax.tree_util.tree_map(lambda x: x[0], theta)
@@ -1253,6 +1261,7 @@ class FederationEngine:
         local = self._local_phase(n_steps, step_masked, pass_n_valid)
         compressed = self._compressed and mixing
 
+        @jax.named_scope("fl.exchange")
         def exchange(trained, buf_t, buf_w, kept, sent, key, ef_state):
             theta_tree = trained["proxy"]["params"]
             like = jax.tree_util.tree_map(lambda x: x[0], theta_tree)
@@ -1420,7 +1429,7 @@ class FederationEngine:
                    "stale_w": buf_w}
             if self._compressed:  # mix-less block: codec state untouched
                 out["ef_state"] = state["ef_state"]
-        return out, self._finish_block(ms, act_stack, data)
+        return out, ms, act_stack
 
     # -- hier backend (two-level factored exchange) --------------------------
 
@@ -1445,6 +1454,7 @@ class FederationEngine:
         local = self._local_phase(n_steps, step_masked, pass_n_valid)
         up, tau = self.use_pallas, self.staleness
 
+        @jax.named_scope("fl.exchange")
         def exchange(trained, blocks, src, scale, buf_t, buf_w):
             theta_tree = trained["proxy"]["params"]
             like = jax.tree_util.tree_map(lambda x: x[0], theta_tree)
@@ -1607,7 +1617,7 @@ class FederationEngine:
             out, ms = self._rounds[rkey](
                 self._clients_of(state), data_s, n_valid, steps_dev,
                 blockss, srcs, scales, jnp.asarray(act_stack), ts, key)
-        return out, self._finish_block(ms, act_stack, data)
+        return out, ms, act_stack
 
     def _build_round(self, n_steps: int, mix_op, step_masked: bool = False,
                      pass_n_valid: bool = True):

@@ -77,23 +77,26 @@ def dml_step_fn(private_spec: ModelSpec, proxy_spec: ModelSpec,
         if cfg.dp.enabled and cfg.use_pallas:
             # fused clip→noise→Adam hot path (repro.kernels); allclose to
             # the dp_gradient + opt.update chain below, never bit-exact
-            theta2, opt_theta2, m_theta = dp_adam_update(
-                lambda t, b: proxy_loss(t, b, phi), theta, opt_theta,
-                batch, key, opt=opt, clip_norm=cfg.dp.clip_norm,
-                noise_multiplier=cfg.dp.noise_multiplier)
-        elif cfg.dp.enabled:
-            g_theta, m_theta = dp_gradient(
-                lambda t, b: proxy_loss(t, b, phi), theta, batch, key,
-                clip_norm=cfg.dp.clip_norm,
-                noise_multiplier=cfg.dp.noise_multiplier,
-                vectorized=cfg.dp.vectorized)
-            theta2, opt_theta2 = opt.update(g_theta, opt_theta, theta)
+            with jax.named_scope("fl.proxy"):
+                theta2, opt_theta2, m_theta = dp_adam_update(
+                    lambda t, b: proxy_loss(t, b, phi), theta, opt_theta,
+                    batch, key, opt=opt, clip_norm=cfg.dp.clip_norm,
+                    noise_multiplier=cfg.dp.noise_multiplier)
         else:
-            g_theta, m_theta = non_dp_gradient(
-                lambda t, b: proxy_loss(t, b, phi), theta, batch)
+            with jax.named_scope("fl.proxy"):
+                if cfg.dp.enabled:
+                    g_theta, m_theta = dp_gradient(
+                        lambda t, b: proxy_loss(t, b, phi), theta, batch,
+                        key, clip_norm=cfg.dp.clip_norm,
+                        noise_multiplier=cfg.dp.noise_multiplier,
+                        vectorized=cfg.dp.vectorized)
+                else:
+                    g_theta, m_theta = non_dp_gradient(
+                        lambda t, b: proxy_loss(t, b, phi), theta, batch)
             theta2, opt_theta2 = opt.update(g_theta, opt_theta, theta)
-        g_phi, m_phi = non_dp_gradient(
-            lambda p, b: private_loss(p, b, theta), phi, batch)
+        with jax.named_scope("fl.private"):
+            g_phi, m_phi = non_dp_gradient(
+                lambda p, b: private_loss(p, b, theta), phi, batch)
         phi2, opt_phi2 = opt.update(g_phi, opt_phi, phi)
         return phi2, opt_phi2, theta2, opt_theta2, {
             "private_loss": m_phi["loss"], "proxy_loss": m_theta["loss"]}

@@ -223,7 +223,9 @@ def make_train_step(cfg_priv: ModelConfig, cfg_proxy: ModelConfig,
             own, aux = _forward_logits(phi, cfg_priv, t_, i_, opts)
             return dml_loss(own, peer, l_, fl.alpha) + aux
 
-        g_phi, m_phi = non_dp_gradient(ploss, phi0, batch, accum=opts.accum)
+        with jax.named_scope("fl.private"):
+            g_phi, m_phi = non_dp_gradient(ploss, phi0, batch,
+                                           accum=opts.accum)
 
         # ---- proxy model: Eq. (5) with per-example DP-SGD (Eq. 7).
         # The private peer logits depend only on phi0, so they are computed
@@ -240,19 +242,20 @@ def make_train_step(cfg_priv: ModelConfig, cfg_proxy: ModelConfig,
             own, aux = _forward_logits(theta, cfg_proxy, t_, i_, opts)
             return dml_loss(own, ex["peer"], l_, fl.beta) + aux
 
-        if fl.dp.enabled:
-            g_theta, m_theta = dp_gradient_chunked(
-                xloss, theta0, batch, key,
-                clip_norm=fl.dp.clip_norm,
-                noise_multiplier=fl.dp.noise_multiplier,
-                chunk=opts.dp_chunk,
-                constrain=lambda b: _constrain_batch(b, opts),
-                prepare_chunk=add_peer,
-                spmd_axis_name="data" if opts.shard_acts else None)
-        else:
-            g_theta, m_theta = non_dp_gradient(
-                lambda th, b: xloss(th, add_peer(b)), theta0, batch,
-                accum=opts.accum)
+        with jax.named_scope("fl.proxy"):
+            if fl.dp.enabled:
+                g_theta, m_theta = dp_gradient_chunked(
+                    xloss, theta0, batch, key,
+                    clip_norm=fl.dp.clip_norm,
+                    noise_multiplier=fl.dp.noise_multiplier,
+                    chunk=opts.dp_chunk,
+                    constrain=lambda b: _constrain_batch(b, opts),
+                    prepare_chunk=add_peer,
+                    spmd_axis_name="data" if opts.shard_acts else None)
+            else:
+                g_theta, m_theta = non_dp_gradient(
+                    lambda th, b: xloss(th, add_peer(b)), theta0, batch,
+                    accum=opts.accum)
 
         phi1, opt_phi1 = opt.update(g_phi, state["private"]["opt"], phi0)
         theta1, opt_theta1 = opt.update(g_theta, state["proxy"]["opt"], theta0)

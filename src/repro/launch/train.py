@@ -94,17 +94,22 @@ def build_cfgs(args):
 def _ppl_loss(cfg: ModelConfig):
     """One jitted eval loss per config (a fresh jit per call would compile
     the private forward again at every block edge)."""
-    return jax.jit(lambda p, t: cross_entropy(
-        forward(p, cfg, t[:, :-1])[0], t[:, 1:]))
+    @jax.jit
+    @jax.named_scope("fl.eval")
+    def loss(p, t):
+        return cross_entropy(forward(p, cfg, t[:, :-1])[0], t[:, 1:])
+
+    return loss
 
 
 def evaluate_ppl(params, cfg: ModelConfig, tokens: jnp.ndarray, batch: int = 8
                  ) -> float:
-    losses = []
-    fwd = _ppl_loss(cfg)
-    for i in range(0, tokens.shape[0], batch):
-        losses.append(float(fwd(params, tokens[i:i + batch])))
-    return float(np.exp(np.mean(losses)))
+    with jax.profiler.TraceAnnotation("fl.edge.eval"):
+        losses = []
+        fwd = _ppl_loss(cfg)
+        for i in range(0, tokens.shape[0], batch):
+            losses.append(float(fwd(params, tokens[i:i + batch])))
+        return float(np.exp(np.mean(losses)))
 
 
 def make_engine(cfg: ModelConfig, proxy: ModelConfig, fl: ProxyFLConfig,

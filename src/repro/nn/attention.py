@@ -45,6 +45,7 @@ def _attend_dense(q, k, v, q_pos, kv_pos, window, scale):
     return o / jnp.maximum(l, 1e-30).transpose(0, 3, 1, 2)[..., None]
 
 
+@jax.named_scope("fl.attention")
 def attend(
     q: jnp.ndarray,  # [B, Sq, Hq, Dqk]
     k: jnp.ndarray,  # [B, Sk, Hkv, Dqk]

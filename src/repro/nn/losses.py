@@ -43,6 +43,7 @@ def kl_divergence(p_logits: jnp.ndarray, q_logits: jnp.ndarray,
     return jnp.sum(kl * mask) / jnp.maximum(jnp.sum(mask), 1.0)
 
 
+@jax.named_scope("fl.loss")
 def dml_loss(own_logits, peer_logits, labels, alpha: float,
              mask: Optional[jnp.ndarray] = None) -> jnp.ndarray:
     """(1-alpha)·CE(own, y) + alpha·KL(own ‖ stop_grad(peer)) — Eq. 4/5."""

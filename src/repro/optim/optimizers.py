@@ -50,6 +50,7 @@ class Adam:
         return AdamState(zeros(params), zeros(params),
                          jnp.zeros((), jnp.int32), p32)
 
+    @jax.named_scope("fl.adam")
     def update(self, grads: Params, state: AdamState, params: Params
                ) -> Tuple[Params, AdamState]:
         t = state.t + 1

@@ -44,8 +44,7 @@ def test_run_registry_lists_every_fig_module(capsys):
 def test_run_registry_tiers_cover_every_module(capsys):
     """Every registry entry carries a runtime tier, the tier shows up in
     ``--list``, and ``names_for_tier`` partitions the registry — the hook
-    CI's non-gating baseline step selects figures through (so ci.sh never
-    hard-codes module names)."""
+    ``benchmarks.run --tier`` selects figures through."""
     import benchmarks.run as run
     for name, entry in run.MODULES.items():
         assert len(entry) == 3, f"{name}: registry entry missing tier field"
@@ -54,7 +53,7 @@ def test_run_registry_tiers_cover_every_module(capsys):
     full = run.names_for_tier("full")
     assert set(fast) | set(full) == set(run.MODULES)
     assert not set(fast) & set(full)
-    # the CI baseline slice: the cheap timing figures, including hier
+    # the fast tier: the figures that finish in CPU minutes, hier included
     assert {"fig_blocks", "fig_kernels", "fig_hier"} <= set(fast)
     with pytest.raises(ValueError, match="tier"):
         run.names_for_tier("nope")
@@ -64,35 +63,6 @@ def test_run_registry_tiers_cover_every_module(capsys):
         name = line.split(":", 1)[0]
         assert f"({run.MODULES[name][2]})" in line, \
             f"{name}: tier absent from --list line"
-
-
-@pytest.mark.fast
-def test_bench_baseline_rows_are_schema_stable():
-    """Every figure's rows normalize to the SAME five keys — the artifact
-    contract CI archives across commits."""
-    import importlib.util
-    spec = importlib.util.spec_from_file_location(
-        "bench_baseline", os.path.join(REPO, "scripts", "bench_baseline.py"))
-    bb = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(bb)
-    samples = [
-        ("fig_blocks", {"clients": 8, "backend": "vmap",
-                        "rounds_per_sec": 12.5}),
-        ("fig_kernels", {"clients": 4, "path": "fused",
-                         "rounds_per_sec": 3.1,
-                         "exchange_bytes_per_round": 1024}),
-        ("fig_hier", {"K": 256, "backend": "hier", "n_shards": 8,
-                      "staleness": 2, "rounds_per_sec": 40.0,
-                      "bytes_cross_per_client": 55000.0}),
-    ]
-    keys = {"figure", "K", "backend", "rounds_per_sec", "bytes_per_round"}
-    for figure, row in samples:
-        out = bb._normalize(figure, row)
-        assert set(out) == keys, f"{figure}: schema drifted: {set(out)}"
-    assert bb._normalize(*samples[1])["backend"] == "vmap-fused"
-    assert bb._normalize(*samples[2])["backend"] == "hier-s8-t2"
-    assert bb._normalize(*samples[2])["bytes_per_round"] == 55000.0
-    assert bb._normalize(*samples[0])["bytes_per_round"] is None
 
 
 def test_task_seed_is_process_independent():

@@ -168,3 +168,45 @@ def test_llm_round_block_fits_one_v5e(one_chip, monkeypatch):
     peak = (mem.argument_size_in_bytes + mem.temp_size_in_bytes
             + mem.output_size_in_bytes - mem.alias_size_in_bytes)
     assert peak < 12 * 2 ** 30, peak
+
+
+def test_llm_round_block_ops_carry_the_program_scopes(one_chip):
+    """Compiled for the chip, every matmul of the LLM round-block (vmap
+    backend, DP proxy) names the local phase or the exchange in its
+    metadata, and the step's scopes all reach the compiled program: the
+    benchmark's by-scope reduction reads these names from the device
+    trace's op metadata."""
+    import re
+
+    from repro.configs import get_config
+    from repro.configs.base import DPConfig, ProxyFLConfig
+    from repro.configs.registry import proxy_of, smoke_variant
+    from repro.launch.train import make_engine
+
+    K, B, S, T, steps = 2, 2, 16, 2, 1
+    cfg = smoke_variant(get_config("qwen1.5-4b"))
+    fl = ProxyFLConfig(n_clients=K, local_steps=steps, batch_size=B,
+                       dp=DPConfig(enabled=True))
+    eng = make_engine(cfg, smoke_variant(proxy_of(cfg)), fl)
+    block = eng._build_block(T, steps, eng._mix_matmul_op())
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    key = jax.eval_shape(lambda: jax.random.PRNGKey(0))
+    state = jax.tree_util.tree_map(
+        lambda x: sds(x.shape, x.dtype), jax.eval_shape(eng.init_states, key))
+    hlo = block.lower(
+        state, sds((K, 4, S + 1), jnp.int32), sds((K,), jnp.int32),
+        sds((K,), jnp.int32), sds((T, K, K), jnp.float32),
+        sds((T, K), jnp.bool_), sds((T,), jnp.int32),
+        sds(key.shape, key.dtype)).compile().as_text()
+    matmuls = [line for line in hlo.splitlines()
+               if re.search(r"= \S+ (dot|convolution)\(", line)]
+    assert matmuls
+    assert [m for m in matmuls
+            if not re.search(r'op_name="[^"]*fl\.(local|exchange)/', m)] == []
+    names = re.findall(r'op_name="([^"]*)"', hlo)
+    for scope in ("fl.private", "fl.proxy", "fl.adam", "fl.loss",
+                  "fl.attention", "fl.exchange"):
+        assert any(scope in n for n in names), scope
