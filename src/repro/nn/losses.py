@@ -7,6 +7,32 @@ import jax
 import jax.numpy as jnp
 
 
+def _log_softmax(x: jnp.ndarray) -> jnp.ndarray:
+    """log-softmax over the last axis as plain row reductions: a row max,
+    then x - (log-sum-exp + max) in one subtraction. Compiled for a TPU
+    with its gradient, over logits that the head's matmul lays out with the
+    vocabulary on sublanes, ``jax.nn.log_softmax``'s form (subtract the max,
+    then the log-sum-exp) has the compiler fold the row max into a
+    reduce-window as wide as the row, centred on every entry: quadratic in
+    V. ``tests/test_tpu_compile.py`` holds the DML head to none."""
+    m = jax.lax.stop_gradient(jnp.max(x, axis=-1, keepdims=True))
+    return x - (jnp.log(jnp.sum(jnp.exp(x - m), axis=-1, keepdims=True)) + m)
+
+
+def _picked(x: jnp.ndarray, labels: jnp.ndarray) -> jnp.ndarray:
+    """x[..., labels] by an iota compare, a vocab-local reduction."""
+    iota = jax.lax.broadcasted_iota(jnp.int32, x.shape, x.ndim - 1)
+    return jnp.sum(jnp.where(iota == labels[..., None].astype(jnp.int32), x, 0.0),
+                   axis=-1)
+
+
+def _masked_mean(x: jnp.ndarray, mask: Optional[jnp.ndarray]) -> jnp.ndarray:
+    if mask is None:
+        return jnp.mean(x)
+    mask = jnp.broadcast_to(mask, x.shape).astype(jnp.float32)
+    return jnp.sum(x * mask) / jnp.maximum(jnp.sum(mask), 1.0)
+
+
 def cross_entropy(logits: jnp.ndarray, labels: jnp.ndarray,
                   mask: Optional[jnp.ndarray] = None) -> jnp.ndarray:
     """Mean CE. logits [..., V]; labels [...] int; mask broadcastable to
@@ -17,48 +43,37 @@ def cross_entropy(logits: jnp.ndarray, labels: jnp.ndarray,
     parallel mesh with vocab-sharded logits every term stays local and only
     [..,] -shaped partials cross the "model" axis — a gather of the full
     logits tensor otherwise dominates collective traffic."""
-    lf = logits.astype(jnp.float32)
-    m = jax.lax.stop_gradient(jnp.max(lf, axis=-1, keepdims=True))
-    lse = jnp.log(jnp.sum(jnp.exp(lf - m), axis=-1)) + m[..., 0]
-    iota = jax.lax.broadcasted_iota(jnp.int32, lf.shape, lf.ndim - 1)
-    picked = jnp.sum(jnp.where(iota == labels[..., None].astype(jnp.int32), lf, 0.0),
-                     axis=-1)
-    nll = lse - picked
-    if mask is None:
-        return jnp.mean(nll)
-    mask = jnp.broadcast_to(mask, nll.shape).astype(jnp.float32)
-    return jnp.sum(nll * mask) / jnp.maximum(jnp.sum(mask), 1.0)
+    lp = _log_softmax(logits.astype(jnp.float32))
+    return _masked_mean(-_picked(lp, labels), mask)
+
+
+def _kl(lp: jnp.ndarray, lq: jnp.ndarray) -> jnp.ndarray:
+    """KL[p || q] per position, from log-probabilities."""
+    return jnp.sum(jnp.exp(lp) * (lp - lq), axis=-1)
 
 
 def kl_divergence(p_logits: jnp.ndarray, q_logits: jnp.ndarray,
                   mask: Optional[jnp.ndarray] = None) -> jnp.ndarray:
     """Mean KL[p || q] over positions (paper Eq. 3). Differentiable wrt both;
     callers stop-gradient the frozen side per the DML alternation."""
-    lp = jax.nn.log_softmax(p_logits.astype(jnp.float32), axis=-1)
-    lq = jax.nn.log_softmax(q_logits.astype(jnp.float32), axis=-1)
-    kl = jnp.sum(jnp.exp(lp) * (lp - lq), axis=-1)
-    if mask is None:
-        return jnp.mean(kl)
-    mask = jnp.broadcast_to(mask, kl.shape).astype(jnp.float32)
-    return jnp.sum(kl * mask) / jnp.maximum(jnp.sum(mask), 1.0)
+    return _masked_mean(_kl(_log_softmax(p_logits.astype(jnp.float32)),
+                            _log_softmax(q_logits.astype(jnp.float32))), mask)
 
 
 @jax.named_scope("fl.loss")
 def dml_loss(own_logits, peer_logits, labels, alpha: float,
              mask: Optional[jnp.ndarray] = None) -> jnp.ndarray:
-    """(1-alpha)·CE(own, y) + alpha·KL(own ‖ stop_grad(peer)) — Eq. 4/5."""
-    peer = jax.lax.stop_gradient(peer_logits)
-    return ((1.0 - alpha) * cross_entropy(own_logits, labels, mask)
-            + alpha * kl_divergence(own_logits, peer, mask))
+    """(1-alpha)·CE(own, y) + alpha·KL(own ‖ stop_grad(peer)) — Eq. 4/5.
+    CE and KL share one log-softmax of the own logits."""
+    lp = _log_softmax(own_logits.astype(jnp.float32))
+    lq = _log_softmax(jax.lax.stop_gradient(peer_logits).astype(jnp.float32))
+    return ((1.0 - alpha) * _masked_mean(-_picked(lp, labels), mask)
+            + alpha * _masked_mean(_kl(lp, lq), mask))
 
 
 def accuracy(logits, labels, mask: Optional[jnp.ndarray] = None) -> jnp.ndarray:
     pred = jnp.argmax(logits, axis=-1)
-    ok = (pred == labels).astype(jnp.float32)
-    if mask is None:
-        return jnp.mean(ok)
-    mask = jnp.broadcast_to(mask, ok.shape).astype(jnp.float32)
-    return jnp.sum(ok * mask) / jnp.maximum(jnp.sum(mask), 1.0)
+    return _masked_mean((pred == labels).astype(jnp.float32), mask)
 
 
 def macro_accuracy(logits, labels, n_classes: int) -> jnp.ndarray:

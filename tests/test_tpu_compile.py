@@ -210,3 +210,27 @@ def test_llm_round_block_ops_carry_the_program_scopes(one_chip):
     for scope in ("fl.private", "fl.proxy", "fl.adam", "fl.loss",
                   "fl.attention", "fl.exchange"):
         assert any(scope in n for n in names), scope
+
+
+def test_dml_loss_head_compiles_without_reduce_window(one_chip):
+    """The DML loss head over Phi-3-width logits ``[4 clients, 4, 512,
+    8016]``, vmapped over the clients with its gradient, as the round-block
+    runs it. The own logits come out of the head's matmul, which lays them
+    out with the vocabulary on sublanes; a log-softmax whose row max the
+    compiler turns into a reduce-window as wide as the row is quadratic in
+    V there, and took the largest share of the round on the chip."""
+    from repro.nn.losses import dml_loss
+
+    K, B, S, d, V = 4, 4, 512, 3072, 8016
+
+    def loss(w, h, peer, labels):
+        return dml_loss(h @ w, peer, labels, 0.5)
+
+    def sds(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    hlo = jax.jit(jax.vmap(jax.value_and_grad(loss))).lower(
+        sds((K, d, V)), sds((K, B, S, d)), sds((K, B, S, V)),
+        sds((K, B, S), jnp.int32)).compile().as_text()
+    assert "exponential" in hlo
+    assert "reduce-window" not in hlo
