@@ -264,10 +264,11 @@ def dp_gradient_chunked(
     individually); ``chunk`` trades peak gradient memory (chunk × |θ|)
     against scan trip count — the knob the §Perf loop tunes on TPU.
 
-    ``prepare_chunk`` runs ONCE per chunk, outside the per-example vmap —
-    the ProxyFL step uses it to compute the (θ-independent) private-peer
-    logits with one batched forward instead of once per example, which on
-    a mesh removes per-example traversals of the large private model.
+    ``prepare_chunk`` runs ONCE per chunk, outside the per-example vmap.
+    The ProxyFL step passes the private peer logits as a batch leaf when
+    its private step made them for the whole batch; when that step runs in
+    microbatches it uses this hook to compute them (θ-independent) with one
+    batched forward per chunk instead of once per example.
     ``spmd_axis_name`` shards the vmapped example dim over that mesh axis
     (GSPMD would otherwise be free to replicate the per-example compute)."""
     B = jax.tree_util.tree_leaves(batch)[0].shape[0]
@@ -344,9 +345,17 @@ def non_dp_gradient(
     batch: Any,
     *,
     accum: int = 1,
-) -> Tuple[Params, dict]:
+    has_aux: bool = False,
+):
     """Plain mean gradient, optionally accumulated over ``accum`` microbatch
-    slices with a scan (bounds peak logits memory for large-vocab models)."""
+    slices with a scan (bounds peak logits memory for large-vocab models).
+
+    With ``has_aux`` the loss returns ``(loss, aux)`` and the whole batch's
+    ``aux`` comes back third; only an unsliced batch (``accum`` 1) has one."""
+    if has_aux:
+        assert accum <= 1, accum
+        (loss, aux), g = jax.value_and_grad(loss_fn, has_aux=True)(params, batch)
+        return g, {"loss": loss}, aux
     if accum <= 1:
         loss, g = jax.value_and_grad(loss_fn)(params, batch)
         return g, {"loss": loss}

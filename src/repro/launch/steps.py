@@ -215,27 +215,37 @@ def make_train_step(cfg_priv: ModelConfig, cfg_proxy: ModelConfig,
         # ---- private model: Eq. (4), non-DP, microbatch-accumulated.
         # The proxy peer logits are recomputed per microbatch inside the
         # loss (theta0 is closed over; accumulation slices tokens/labels/img
-        # together through the batch dict).
+        # together through the batch dict). The loss also returns its own
+        # logits at phi0: the proxy step's private peer.
         def ploss(phi, mb):
             mb = _constrain_batch(mb, opts)
             t_, l_, i_ = mb["tokens"], mb["labels"], mb.get("img")
             peer, _ = _forward_logits(theta0, cfg_proxy, t_, i_, opts)
             own, aux = _forward_logits(phi, cfg_priv, t_, i_, opts)
-            return dml_loss(own, peer, l_, fl.alpha) + aux
-
-        with jax.named_scope("fl.private"):
-            g_phi, m_phi = non_dp_gradient(ploss, phi0, batch,
-                                           accum=opts.accum)
+            return dml_loss(own, peer, l_, fl.alpha) + aux, own
 
         # ---- proxy model: Eq. (5) with per-example DP-SGD (Eq. 7).
-        # The private peer logits depend only on phi0, so they are computed
-        # ONCE per DP chunk with a batched forward (prepare_chunk) and
-        # threaded into the per-example loss — one extra private forward
-        # over the batch in total, never per example.
+        # Its private peer logits depend only on phi0. Unsliced (accum 1),
+        # the private step has just made them for the whole batch, so they
+        # ride along as one more batch leaf. Microbatched, holding [B, S, V]
+        # logits across the step would undo what accum bounds, so add_peer
+        # recomputes them once per DP chunk (or microbatch) with a batched
+        # forward, never per example.
         def add_peer(cb):
             peer, _ = _forward_logits(phi0, cfg_priv, cb["tokens"],
                                       cb.get("img"), opts)
             return dict(cb, peer=peer)
+
+        with jax.named_scope("fl.private"):
+            if opts.accum <= 1:
+                g_phi, m_phi, own = non_dp_gradient(ploss, phi0, batch,
+                                                    has_aux=True)
+                peer = jax.lax.stop_gradient(own)
+                pbatch, prepare = dict(batch, peer=peer), (lambda b: b)
+            else:
+                g_phi, m_phi = non_dp_gradient(lambda p, b: ploss(p, b)[0],
+                                               phi0, batch, accum=opts.accum)
+                pbatch, prepare = batch, add_peer
 
         def xloss(theta, ex):
             t_, l_, i_ = ex["tokens"], ex["labels"], ex.get("img")
@@ -245,16 +255,16 @@ def make_train_step(cfg_priv: ModelConfig, cfg_proxy: ModelConfig,
         with jax.named_scope("fl.proxy"):
             if fl.dp.enabled:
                 g_theta, m_theta = dp_gradient_chunked(
-                    xloss, theta0, batch, key,
+                    xloss, theta0, pbatch, key,
                     clip_norm=fl.dp.clip_norm,
                     noise_multiplier=fl.dp.noise_multiplier,
                     chunk=opts.dp_chunk,
                     constrain=lambda b: _constrain_batch(b, opts),
-                    prepare_chunk=add_peer,
+                    prepare_chunk=prepare,
                     spmd_axis_name="data" if opts.shard_acts else None)
             else:
                 g_theta, m_theta = non_dp_gradient(
-                    lambda th, b: xloss(th, add_peer(b)), theta0, batch,
+                    lambda th, b: xloss(th, prepare(b)), theta0, pbatch,
                     accum=opts.accum)
 
         phi1, opt_phi1 = opt.update(g_phi, state["private"]["opt"], phi0)
