@@ -16,7 +16,7 @@ import time
 from . import (fig3_accuracy, fig4_comm, fig5_ablations, fig6_kvasir,
                fig11_batchsize, fig_async, fig_blocks, fig_compress,
                fig_dropout, fig_hier, fig_kernels, fig_ragged, mia_privacy,
-               roofline, table2_histo)
+               table2_histo)
 
 # name -> (module, paper anchor, runtime tier). The one-line description
 # shown by ``--list`` is each module's own docstring first line, so
@@ -39,7 +39,6 @@ MODULES = {
     "fig_async": (fig_async, "beyond-paper", "full"),
     "fig_dropout": (fig_dropout, "paper §3.4", "full"),
     "mia_privacy": (mia_privacy, "beyond-paper", "full"),
-    "roofline": (roofline, "§Roofline", "full"),
 }
 
 TIERS = ("fast", "full")

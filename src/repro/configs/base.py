@@ -130,7 +130,7 @@ class ModelConfig:
     def with_(self, **kw) -> "ModelConfig":
         return dataclasses.replace(self, **kw)
 
-    # -- parameter count (analytic, for roofline MODEL_FLOPS) ---------------
+    # -- parameter count (analytic) ------------------------------------------
     def param_counts(self) -> dict:
         """Analytic parameter counts: total and active-per-token."""
         d = self.d_model

@@ -254,9 +254,7 @@ def dp_gradient_chunked(
     clip_norm: float,
     noise_multiplier: float,
     chunk: int = 8,
-    constrain: Callable[[Any], Any] = lambda b: b,
     prepare_chunk: Callable[[Any], Any] = lambda b: b,
-    spmd_axis_name=None,
 ) -> Tuple[Params, dict]:
     """Per-example DP-SGD gradient (Eq. 7) with a scan-of-vmap schedule:
     scan over B/chunk chunks, vmap the per-example grads inside each chunk.
@@ -268,9 +266,7 @@ def dp_gradient_chunked(
     The ProxyFL step passes the private peer logits as a batch leaf when
     its private step made them for the whole batch; when that step runs in
     microbatches it uses this hook to compute them (θ-independent) with one
-    batched forward per chunk instead of once per example.
-    ``spmd_axis_name`` shards the vmapped example dim over that mesh axis
-    (GSPMD would otherwise be free to replicate the per-example compute)."""
+    batched forward per chunk instead of once per example."""
     B = jax.tree_util.tree_leaves(batch)[0].shape[0]
     assert B % chunk == 0, (B, chunk)
     n_chunks = B // chunk
@@ -278,20 +274,15 @@ def dp_gradient_chunked(
         p, jax.tree_util.tree_map(lambda x: x[None], ex)))
 
     def per_chunk(i):
-        # ``constrain`` pins the chunk dim to the "data" mesh axis on the
-        # distributed path so the vmapped per-example grads divide across
-        # data rows instead of being computed redundantly on every device.
-        return prepare_chunk(constrain(jax.tree_util.tree_map(
-            lambda x: jax.lax.dynamic_slice_in_dim(x, i * chunk, chunk, 0), batch)))
+        return prepare_chunk(jax.tree_util.tree_map(
+            lambda x: jax.lax.dynamic_slice_in_dim(x, i * chunk, chunk, 0), batch))
 
     def body(carry, i):
         acc, loss_sum, norm_sum = carry
-        losses, grads = jax.vmap(grad_fn, in_axes=(None, 0),
-                                 spmd_axis_name=spmd_axis_name)(params, per_chunk(i))
+        losses, grads = jax.vmap(grad_fn, in_axes=(None, 0))(params, per_chunk(i))
         norms = jax.vmap(lambda g: jnp.sqrt(sum(
             jnp.sum(jnp.square(x.astype(jnp.float32)))
-            for x in jax.tree_util.tree_leaves(g))),
-                         spmd_axis_name=spmd_axis_name)(grads)
+            for x in jax.tree_util.tree_leaves(g))))(grads)
         scales = (1.0 / jnp.maximum(1.0, norms / clip_norm)).astype(jnp.float32)
         acc = jax.tree_util.tree_map(
             lambda a, g: a + jnp.einsum(

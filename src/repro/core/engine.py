@@ -4,7 +4,7 @@ The paper's round structure (Algorithm 1: ``local_steps`` local updates per
 client, then one exchange over a column-stochastic graph) is shared by every
 method in the METHODS table — ProxyFL, FML, FedAvg, AvgPush, CWT, Regular —
 and by the LLM-scale driver in ``launch/train.py``. This module owns that
-round once, behind three selectable backends:
+round once, behind five selectable backends:
 
 ``loop``
     One Python iteration per client per step, each client's step jitted
@@ -81,9 +81,8 @@ round once, behind three selectable backends:
     ``fused_pushsum_mix``-eligible under ``use_pallas``, vmapped over the
     shard axis) and inter-shard exchange is a sparse scaled permutation
     (at most ONE cross-shard edge per client per round — exactly the
-    structure a ``ppermute`` collective realizes on a real device mesh;
-    see ``launch/steps.py``/``launch/dryrun.py --program hier_block`` for
-    the mesh deployment). Crucially this is NOT a different protocol:
+    structure a ``ppermute`` collective realizes on a real device mesh).
+    Crucially this is NOT a different protocol:
     hier executes the SAME flat column-stochastic schedule P^(t) as vmap,
     FACTORED by edge locality (:func:`repro.core.gossip.hier_mix_split`:
     P = blockdiag[S, L, L] + cross scaled partial permutation — an exact
@@ -159,14 +158,9 @@ written there (a kill/resume lands on an edge and replays bit-identically
 — drivers cut blocks so every checkpoint/eval cadence round IS an edge),
 evaluation and history rows read there, DP accountants bulk-step there
 (``PrivacyAccountant.step(n)``), and §3.4 join/leave membership is
-resolved there for the whole block (``active_schedule``). This is the
-prerequisite for the planned ASYNC fourth backend: overlap-friendly
-variants (clients gossiping stale proxies while the next local scan runs,
-Assran et al.) need the engine — not the caller — to own a multi-round
-horizon inside which rounds may interleave, while the block edge stays
-the only point where external observers (checkpointer, evaluator,
-membership changes) interact with the federation. The ``async`` backend
-is exactly that fourth backend: rounds interleave INSIDE a block through
+resolved there for the whole block (``active_schedule``). The ``async``
+backend relies on this: rounds interleave INSIDE a block (clients gossip
+stale proxies while the next local scan runs, Assran et al.) through
 the τ-deep in-flight buffer carried in the block scan's state, while the
 block edge stays the only host-visible boundary — the buffer is snapshot
 and restored there, so kill/resume stays bit-identical at any τ. When is

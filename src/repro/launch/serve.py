@@ -3,9 +3,7 @@ client's private model can be used for inference") at LLM scale.
 
 Implements a simple static-batch serving loop: prefill the prompt batch
 into the KV/SSM cache, then step the decode loop token by token with greedy
-or temperature sampling. On CPU this serves the reduced (smoke) variant;
-full-size serving programs are exercised via ``dryrun.py`` (prefill_32k /
-decode_32k / long_500k).
+or temperature sampling. On CPU this serves the reduced (smoke) variant.
 
 Example::
 

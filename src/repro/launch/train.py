@@ -27,9 +27,8 @@ the cross-shard edges. ``--dropout-rate``
 exercises the §3.4 dropout/join scenario: clients sit rounds out and the
 time-varying gossip graph re-knits around them.
 
-On CPU this runs the reduced (smoke) variant of the chosen architecture;
-the full-size configs are exercised through ``dryrun.py``. The default
-``--preset 100m`` trains a ~100M-parameter private model.
+On CPU this runs the reduced (smoke) variant of the chosen architecture.
+The default ``--preset 100m`` trains a ~100M-parameter private model.
 
 ``--checkpoint-dir`` snapshots the complete federation (client states,
 PushSum weights, round counter, DP accountant steps) every

@@ -58,13 +58,11 @@ def apply_layer(
     cache: Optional[Params] = None,
     kv_chunk: int = 1024,
     mamba_chunk: int = 256,
-    moe_expert_axis=None,
-    batch_axis=None,
 ) -> Tuple[jnp.ndarray, Optional[Params], jnp.ndarray]:
     h = rmsnorm(p["norm1"], x)
     if spec.kind == "mamba":
         y, new_cache = apply_mamba(p["mixer"], cfg, h, cache=cache,
-                                   chunk=mamba_chunk, batch_axis=batch_axis)
+                                   chunk=mamba_chunk)
     elif cfg.attn_impl == "mla":
         y, new_cache = apply_mla(p["mixer"], cfg, spec, h, pos_offset=pos_offset,
                                  cache=cache, kv_chunk=kv_chunk)
@@ -76,7 +74,7 @@ def apply_layer(
     if "ffn" in p:
         h = rmsnorm(p["norm2"], x)
         if spec.ffn == "moe":
-            y, aux = apply_moe(p["ffn"], cfg, h, expert_axis=moe_expert_axis)
+            y, aux = apply_moe(p["ffn"], cfg, h)
         else:
             y = mlp(p["ffn"], h)
         x = x + y

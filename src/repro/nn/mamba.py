@@ -96,30 +96,12 @@ def apply_mamba(
     *,
     cache: Optional[Params] = None,
     chunk: int = 256,
-    batch_axis=None,
 ) -> Tuple[jnp.ndarray, Optional[Params]]:
-    """``batch_axis`` re-pins the batch dim of the chunked-scan tensors to
-    that mesh axis: the pad/reshape/swapaxes chain below otherwise loses the
-    batch sharding in GSPMD propagation, replicating multi-GiB [n, B, C,
-    di, ds] scan buffers on every device (observed on jamba × train_4k)."""
     m = cfg.mamba or MambaConfig()
     B, S, _ = x.shape
     di = cfg.d_inner
     xz = linear(p["in_proj"], x)
     x1, z = xz[..., :di], xz[..., di:]
-
-    def pin(t, b_dim, di_dim=None):
-        # NOTE: with_sharding_constraint specs are TOTAL — a None dim means
-        # "replicated", so the d_inner dim must keep its tensor-parallel
-        # axis explicitly (framework convention: the TP axis is "model").
-        if batch_axis is None:
-            return t
-        from jax.sharding import PartitionSpec as P
-        spec = [None] * t.ndim
-        spec[b_dim] = batch_axis
-        if di_dim is not None and t.shape[di_dim] == di:
-            spec[di_dim] = "model"
-        return jax.lax.with_sharding_constraint(t, P(*spec))
 
     prev_conv = cache["conv"] if cache is not None else None
     xc = jax.nn.silu(_causal_conv(p, x1, prev_conv))
@@ -145,15 +127,14 @@ def apply_mamba(
             xcp = jnp.pad(xc, ((0, 0), (0, pad), (0, 0)))
         else:
             xcp = xc
-        resh = lambda t: pin(t.reshape(B, n, chunk, *t.shape[2:]).swapaxes(0, 1),
-                             1, di_dim=3)
+        resh = lambda t: t.reshape(B, n, chunk, *t.shape[2:]).swapaxes(0, 1)
 
         def body(h, xs):
             dtc, bc, cc, xcc = xs
-            y, h_next = _chunk_scan(pin(h, 0, di_dim=1), dtc, A, bc, cc, xcc)
-            return pin(h_next, 0, di_dim=1), pin(y, 0, di_dim=2)
+            y, h_next = _chunk_scan(h, dtc, A, bc, cc, xcc)
+            return h_next, y
 
-        h_last, ys = jax.lax.scan(body, pin(h0, 0, di_dim=1),
+        h_last, ys = jax.lax.scan(body, h0,
                                   (resh(dt), resh(Bm), resh(Cm), resh(xcp)))
         y = ys.swapaxes(0, 1).reshape(B, n * chunk, di)[:, :S]
 

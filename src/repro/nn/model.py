@@ -2,7 +2,7 @@
 
 The layer layout is ``prefix`` (unrolled) + ``pattern`` × R (stacked params,
 executed with ``lax.scan`` so HLO size is O(len(pattern)), not O(n_layers) —
-essential for tractable ``.lower().compile()`` at 512 devices) + ``tail``
+keeping compile time flat in depth) + ``tail``
 (unrolled remainder). KV/SSM caches mirror the same structure so the decode
 path scans too.
 
@@ -130,38 +130,21 @@ def forward(
     remat: bool = False,
     kv_chunk: int = 1024,
     mamba_chunk: int = 256,
-    act_spec: Optional[Tuple] = None,
-    moe_expert_axis=None,
-    batch_axis=None,
 ) -> Tuple[jnp.ndarray, Optional[Cache], jnp.ndarray]:
     """Returns (logits, new_cache, aux_loss). ``cache=None`` → pure forward
     (training); with cache → prefill (S>1) or decode (S==1) at
-    ``pos_offset``. ``act_spec`` (a PartitionSpec tuple for [B, S, d]
-    activations) pins the residual stream between layers — the 2D
-    weight-stationary serving path shards d over "data" so every matmul
-    contracts two similarly-sharded operands (partial-sum + small psum)
-    instead of gathering weights."""
+    ``pos_offset``."""
     P, L, R, rem = _plan(cfg)
     x = _embed_inputs(params, cfg, tokens, img)
-
-    def pin(h):
-        if act_spec is None:
-            return h
-        from jax.sharding import PartitionSpec as PS
-        return jax.lax.with_sharding_constraint(h, PS(*act_spec))
-
-    x = pin(x)
     aux = jnp.zeros((), jnp.float32)
     use_cache = cache is not None
     new_cache: Cache = {"prefix": [], "tail": [], "stack": ()}
     layer_kw = dict(pos_offset=pos_offset, kv_chunk=kv_chunk,
-                    mamba_chunk=mamba_chunk, moe_expert_axis=moe_expert_axis,
-                    batch_axis=batch_axis)
+                    mamba_chunk=mamba_chunk)
 
     for i in range(P):
         x, nc, a = apply_layer(params["prefix"][i], cfg, cfg.prefix[i], x,
                                cache=cache["prefix"][i] if use_cache else None, **layer_kw)
-        x = pin(x)
         aux += a
         new_cache["prefix"].append(nc)
 
@@ -174,7 +157,6 @@ def forward(
                 for pos in range(L):
                     x, nc, a = apply_layer(pp[pos], cfg, cfg.pattern[pos], x,
                                            cache=cc[pos], **layer_kw)
-                    x = pin(x)
                     aux += a
                     ncs.append(nc)
                 return (x, aux), tuple(ncs)
@@ -186,7 +168,6 @@ def forward(
                 for pos in range(L):
                     x, _, a = apply_layer(pp[pos], cfg, cfg.pattern[pos], x,
                                           cache=None, **layer_kw)
-                    x = pin(x)
                     aux += a
                 return (x, aux), None
 

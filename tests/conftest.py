@@ -1,5 +1,5 @@
 """Shared fixtures. NOTE: no XLA_FLAGS here — tests must see ONE CPU device
-(the 512-device override belongs exclusively to repro.launch.dryrun)."""
+(a test that needs more starts a child process with its own XLA_FLAGS)."""
 import jax
 import pytest
 

@@ -161,7 +161,8 @@ def test_blocked_checkpoint_cadence_lands_on_block_edges(tmp_path, fed_data,
 def test_run_rounds_shard_map_block_bit_identical(fed_data, mlp_spec):
     """The shard_map block (per-round collective schedules unrolled inside
     one jit) replays run_round bit-exactly — 1-device mesh smoke; the K=4
-    equivalence runs in the forced multi-device subprocess elsewhere."""
+    equivalence runs in the forced four-device child process of
+    test_engine."""
     from repro.core.engine import FederationEngine
     mesh = jax.make_mesh((1,), ("clients",))
     cfg = ProxyFLConfig(n_clients=1, rounds=2, batch_size=50, local_steps=2,

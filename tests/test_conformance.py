@@ -437,7 +437,7 @@ def test_round_metrics_agree_across_backends(datasets, mlp_spec):
 # ---------------------------------------------------------------------------
 # shard_map column: run_federated cannot construct a mesh, so the 1-device
 # conformance runs at engine level (the K=4 collective equivalence runs in
-# the forced multi-device subprocess of test_system, if present)
+# the forced four-device child process of test_engine)
 
 
 def test_shard_map_k1_matches_vmap_bitwise(datasets, mlp_spec):

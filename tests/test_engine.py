@@ -122,8 +122,8 @@ def test_cwt_ring_is_pure_permutation():
 
 
 # ---------------------------------------------------------------------------
-# shard_map backend (1-device smoke; K=4 equivalence runs in the forced
-# multi-device subprocess of test_system, if present)
+# shard_map backend (1-device smoke; the K=4 equivalence runs in the
+# forced four-device child process below)
 
 
 def test_shard_map_backend_smoke(fed_data, mlp_spec, tmp_path):
@@ -255,11 +255,12 @@ def test_shard_map_state_is_placed_one_client_per_device(fed_data, mlp_spec):
 
 
 _FOUR_DEVICES = """
+import sys
 import jax, numpy as np
 from repro.launch import train
 args = ["--arch", "qwen1.5-4b", "--smoke", "--clients", "4", "--rounds", "2",
         "--steps-per-round", "1", "--batch", "2", "--seq", "16",
-        "--rounds-per-block", "2"]
+        "--rounds-per-block", "2"] + sys.argv[1:]
 sm = train.run(args + ["--backend", "shard_map"])["state"]
 for x in jax.tree_util.tree_leaves(sm):
     assert x.sharding.shard_shape(x.shape)[0] == 1, (x.shape, x.sharding)
@@ -272,11 +273,15 @@ print("FOUR_DEVICES_OK")
 """
 
 
-def test_shard_map_runs_one_client_per_device_on_four_devices():
+@pytest.mark.parametrize("extra", [[], ["--topology", "ring"],
+                                   ["--dropout-rate", "0.25"]],
+                         ids=["exponential", "ring", "dropout"])
+def test_shard_map_runs_one_client_per_device_on_four_devices(extra):
     """On four (virtual CPU) devices the shard_map backend keeps one
     client's state on each device through a round-block and lands close to
-    the vmap backend (bf16 smoke model: one bf16 ulp). Runs in a child:
-    the device count is fixed when JAX starts."""
+    the vmap backend (bf16 smoke model: one bf16 ulp), on the exponential
+    and ring graphs and with §3.4 dropout. Runs in a child: the device
+    count is fixed when JAX starts."""
     import os
     import subprocess
     import sys
@@ -285,7 +290,7 @@ def test_shard_map_runs_one_client_per_device_on_four_devices():
     env = dict(os.environ, JAX_PLATFORMS="cpu",
                XLA_FLAGS="--xla_force_host_platform_device_count=4",
                PYTHONPATH=os.path.join(root, "src"))
-    r = subprocess.run([sys.executable, "-c", _FOUR_DEVICES], env=env,
+    r = subprocess.run([sys.executable, "-c", _FOUR_DEVICES, *extra], env=env,
                        cwd=root, capture_output=True, text=True, timeout=600)
     assert r.returncode == 0 and "FOUR_DEVICES_OK" in r.stdout, \
         r.stdout[-2000:] + r.stderr[-4000:]

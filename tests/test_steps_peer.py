@@ -6,6 +6,7 @@ forward at phi0 already holds the peer the proxy step distils from, so
 model a second time. These tests hold that step to one that recomputes the
 peer with a separate private forward, and check in the compiled program
 that the proxy step runs no private-width matmul."""
+import dataclasses
 import re
 
 import jax
@@ -123,7 +124,8 @@ def test_microbatched_step_recomputes_peer_and_matches(dp):
     got, metrics = jax.jit(make_train_step(priv, proxy, fl, opts))(
         state, batch, key)
     assert bool(jnp.isfinite(metrics["proxy_loss"]))
-    want, _ = jax.jit(make_train_step(priv, proxy, fl, opts.with_(accum=1)))(
+    want, _ = jax.jit(make_train_step(priv, proxy, fl,
+                                     dataclasses.replace(opts, accum=1)))(
         state, batch, key)
     for role in ("private", "proxy"):
         for x, y in zip(jax.tree_util.tree_leaves(got[role]["opt"].m),

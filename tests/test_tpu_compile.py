@@ -1,24 +1,31 @@
-"""Compile the main-path Pallas kernels for a TPU v5e that is described, not
-attached (``jax.experimental.topologies``), at real widths and with
-``interpret=False``: what Mosaic refuses here — misaligned blocks, scalar
-stores to VMEM, scoped-VMEM overflow — would otherwise first fail on the
-chip. Nothing runs, so these tests say nothing about results or times.
+"""Compile the main-path Pallas kernels and round programs for TPU v5e chips
+that are described, not attached (``jax.experimental.topologies``), at real
+widths and with ``interpret=False``: what Mosaic refuses here — misaligned
+blocks, scalar stores to VMEM, scoped-VMEM overflow — would otherwise first
+fail on the chip. Nothing runs, so these tests say nothing about results or
+times.
 
 The topology is described inside a module-scoped fixture, never while a
 module is imported: only one process may load the TPU library, and under
 pytest-xdist only the worker given this file loads it."""
+import contextlib
+import re
+
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
-from jax.sharding import SingleDeviceSharding
+from jax.sharding import NamedSharding, PartitionSpec, SingleDeviceSharding
 
 from repro.kernels import dp_clip, dp_step, pushsum_mix
 
 N_SMALL = 1000
 
 
-@pytest.fixture(scope="module")
-def one_chip():
+@contextlib.contextmanager
+def _described_v5e():
+    """The devices of a described ``v5e:2x2``, with the persistent compile
+    cache switched off while they are in use."""
     from jax.experimental import topologies
     from jax.experimental.compilation_cache import compilation_cache
 
@@ -32,9 +39,22 @@ def one_chip():
     was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
-    yield SingleDeviceSharding(topo.devices[0])
+    yield topo.devices
     jax.config.update("jax_enable_compilation_cache", was)
     compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    with _described_v5e() as devices:
+        yield SingleDeviceSharding(devices[0])
+
+
+@pytest.fixture(scope="module")
+def four_chips():
+    """A one-axis mesh over the four chips, one client per chip."""
+    with _described_v5e() as devices:
+        yield jax.sharding.Mesh(np.array(devices[:4]), ("clients",))
 
 
 @pytest.fixture(scope="module")
@@ -176,8 +196,6 @@ def test_llm_round_block_ops_carry_the_program_scopes(one_chip):
     metadata, and the step's scopes all reach the compiled program: the
     benchmark's by-scope reduction reads these names from the device
     trace's op metadata."""
-    import re
-
     from repro.configs import get_config
     from repro.configs.base import DPConfig, ProxyFLConfig
     from repro.configs.registry import proxy_of, smoke_variant
@@ -234,3 +252,87 @@ def test_dml_loss_head_compiles_without_reduce_window(one_chip):
         sds((K, B, S), jnp.int32)).compile().as_text()
     assert "exponential" in hlo
     assert "reduce-window" not in hlo
+
+
+# name -> what the case changes from pushsum over the exponential graph with
+# a DP proxy and four rounds a block; "mix" goes to FederationEngine,
+# "rounds" sets the block, the rest goes to the config. Every exchange here
+# repeats within two rounds (the exponential graph over four clients
+# alternates two shifts), and every round unrolled adds to the compile.
+FOUR_CHIP_CASES = {
+    "pushsum_dp": {},
+    "pushsum_nodp": {"dp": False, "rounds": 2},
+    "pushsum_ring_graph": {"topology": "ring", "rounds": 2},
+    "mix_ring": {"mix": "ring", "rounds": 2},
+    "mix_none": {"mix": "none", "rounds": 2},
+    "dropout": {"dropout_rate": 0.25},
+}
+
+
+@pytest.mark.parametrize("case", list(FOUR_CHIP_CASES))
+def test_shard_map_round_block_compiles_for_four_v5e_chips(four_chips, case,
+                                                           monkeypatch):
+    """The shard_map backend's round program for the LLM federation, one
+    client per chip of four, compiles for the chips: its exchange is
+    collective-permutes exactly when the case mixes, the state is donated,
+    and the per-chip peak fits a v5e. With dropout the backend runs one
+    round per program, so that program is the one compiled, at a round
+    where some but not all clients take part."""
+    import repro.kernels
+    from repro.configs import get_config
+    from repro.configs.base import DPConfig, ProxyFLConfig
+    from repro.configs.registry import proxy_of, smoke_variant
+    from repro.core.engine import FederationEngine, active_schedule
+    from repro.launch.train import make_engine
+
+    monkeypatch.setattr(repro.kernels, "default_interpret", lambda: False)
+    kw = FOUR_CHIP_CASES[case]
+    K, B, S, T, steps = 4, 2, 16, kw.get("rounds", 4), 1
+    cfg = smoke_variant(get_config("qwen1.5-4b"))
+    fl = ProxyFLConfig(n_clients=K, local_steps=steps, batch_size=B,
+                       topology=kw.get("topology", "exponential"),
+                       dropout_rate=kw.get("dropout_rate", 0.0),
+                       dp=DPConfig(enabled=kw.get("dp", True)))
+    eng = make_engine(cfg, smoke_variant(proxy_of(cfg)), fl,
+                      backend="shard_map", mesh=four_chips)
+    if "mix" in kw:
+        eng = FederationEngine(fl, n_clients=K, step_fns=eng.step_fns,
+                               init_fns=eng.init_fns, sample_fn=eng.sample_fn,
+                               backend="shard_map", mix=kw["mix"],
+                               mesh=eng.mesh)
+    mixing = eng.mix != "none"
+    place = NamedSharding(eng.mesh, PartitionSpec(eng.axis))
+    one = NamedSharding(eng.mesh, PartitionSpec())
+
+    def sds(shape, dtype, sharding=place):
+        return jax.ShapeDtypeStruct(shape, jnp.dtype(dtype), sharding=sharding)
+
+    key = jax.eval_shape(lambda: jax.random.PRNGKey(0))
+    state = jax.tree_util.tree_map(
+        lambda x: sds(x.shape, x.dtype), jax.eval_shape(eng.init_states, key))
+    head = (state, sds((K, 8, S + 1), "int32"), sds((K,), "int32"),
+            sds((K,), "int32", one))
+    if fl.dropout_rate:
+        sched = active_schedule(0, 16, K, fl)
+        t = next(t for t, a in enumerate(sched) if 1 < a.sum() < K)
+        program = eng._build_round(
+            steps, eng._shard_mix_op(t, tuple(bool(a) for a in sched[t])))
+        tail = (sds((0,), "float32", one), sds((K,), "bool", one))
+    else:
+        program = eng._build_block(
+            T, steps, [eng._shard_mix_op(t, None) if mixing else None
+                       for t in range(T)])
+        tail = (sds((T, K, K), "float32", one), sds((T, K), "bool", one),
+                sds((T,), "int32", one))
+    compiled = program.lower(
+        *head, *tail, sds(key.shape, key.dtype, one)).compile()
+    permutes = re.findall(r"\bcollective-permute(?:-start)?\(",
+                          compiled.as_text())
+    assert bool(permutes) == mixing, len(permutes)
+    mem = compiled.memory_analysis()
+    state_bytes = sum(x.size * x.dtype.itemsize
+                      for x in jax.tree_util.tree_leaves(state)) / K
+    assert mem.alias_size_in_bytes >= 0.99 * state_bytes
+    peak = (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            + mem.output_size_in_bytes - mem.alias_size_in_bytes)
+    assert peak < 15.75 * 2 ** 30, peak

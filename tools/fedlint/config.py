@@ -106,13 +106,6 @@ RNG_ALLOWED_SITES: Tuple[Allow, ...] = (
     Allow("src/repro/launch/steps.py", "serve_state_shapes",
           ("PRNGKey", "key"),
           "shape-only eval_shape probe; values never materialize"),
-    Allow("src/repro/launch/steps.py", "make_round_block_step*",
-          ("fold_in",),
-          "dryrun round-block twin of the engine's in-scan round_key fold"),
-    Allow("src/repro/launch/steps.py", "make_hier_round_block_step*",
-          ("fold_in",),
-          "two-level (hier) round-block twin: same fold_in(keys, t) "
-          "per-round schedule as make_round_block_step, one shard per pod"),
     # --- module families with their own key ownership -------------------
     Allow("src/repro/nn/*.py", "*", ("split", "fold_in"),
           "parameter-init trees fan one init key out to sub-module inits; "
